@@ -25,8 +25,8 @@ are identical.  The ``shared-prefix`` setting exercises the block-paged
 cache's prefix reuse; the ``paged-*`` setting measures the Pallas kernels
 themselves — in interpret mode on this CPU container, where skipping
 past-cursor KV blocks still beats rematerializing the gather path's full
-padded virtual width (~2× TPS at the same geometry; on TPU the kernels
-lower natively and the win is the fusion itself, see README).  The twin
+padded virtual width (~2× TPS at the same geometry, a CPU timing; the
+kernels' speed on the TPU is not measured here).  The twin
 costs the schedule's useful work (active slots, valid
 chunk tokens); the measured engine also pays for static-shape padding —
 see the scope note in ``repro.engine.forecast_twin``.

@@ -109,6 +109,8 @@ def main() -> None:
                          "forecast error regressed vs its previous "
                          "BENCH_history.jsonl entry (the CI accuracy gate)")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     only = list(args.modules)
     if args.only:
         only += [m for m in args.only.split(",") if m]

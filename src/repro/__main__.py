@@ -505,6 +505,8 @@ def main(argv: Optional[List[str]] = None) -> int:
     p.set_defaults(fn=_cmd_hardware)
 
     args = parser.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     try:
         return args.fn(args)
     except (KeyError, ValueError) as e:

@@ -21,7 +21,7 @@ single output token:
 """
 from __future__ import annotations
 
-from typing import List, Optional
+from typing import List
 
 from repro.core import hlo
 
@@ -42,7 +42,7 @@ def audit_donation(ct: CompiledTarget) -> List[Finding]:
     Works on the module header alone: collect the entry-parameter shapes
     that alias into outputs and require at least two of them (cache_k and
     cache_v) to be rank-5 pool buffers — ``(n_layers, n_blocks,
-    block_size, n_kv_heads, head_dim)`` up to SPMD partitioning of the
+    n_kv_heads, block_size, head_dim)`` up to SPMD partitioning of the
     layer/head axes, which preserves the rank."""
     t = ct.target
     aliases = hlo.parse_input_output_aliases(ct.hlo_text)
@@ -76,18 +76,6 @@ def audit_donation(ct: CompiledTarget) -> List[Finding]:
 # ---------------------------------------------------------------------------
 # retrace detector (the audit's only execution-based pass)
 # ---------------------------------------------------------------------------
-
-def _cache_size(fn) -> Optional[int]:
-    """Compiled-entry count of a ``jax.jit`` wrapper, or None when the
-    running jax version exposes no cache introspection."""
-    probe = getattr(fn, "_cache_size", None)
-    if probe is None:
-        return None
-    try:
-        return int(probe())
-    except Exception:
-        return None
-
 
 def audit_retrace(cfg: ArchConfig, attn_impl: str = "gather"
                   ) -> List[Finding]:
@@ -125,13 +113,9 @@ def audit_retrace(cfg: ArchConfig, attn_impl: str = "gather"
             f"retrace-audit engine run did not drain in {steps} steps",
             {"steps": steps, "attn_impl": attn_impl}))
         return out
-    checked = False
     for name, fn, budget in (("prefill", eng.prefill_fn, 1),
                              ("decode", eng.decode_fn, 1)):
-        n = _cache_size(fn)
-        if n is None:
-            continue
-        checked = True
+        n = fn._cache_size()
         detail = {"entry_point": name, "compiled_entries": n,
                   "attn_impl": attn_impl, "budget": budget}
         if n > budget:
@@ -146,10 +130,4 @@ def audit_retrace(cfg: ArchConfig, attn_impl: str = "gather"
                 "hygiene", "hygiene.retrace_ok", Severity.INFO,
                 f"engine {name} compiled once across mixed lengths",
                 detail))
-    if not checked:
-        out.append(Finding(
-            "hygiene", "hygiene.no_cache_introspection", Severity.WARNING,
-            "this jax version exposes no jit cache introspection "
-            "(_cache_size) — retrace audit could not run",
-            {"attn_impl": attn_impl}))
     return out

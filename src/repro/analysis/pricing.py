@@ -187,7 +187,7 @@ def lower_target(cfg: ArchConfig, target: PricingTarget,
 
     text = compiled.as_text()
     mc = hlo.analyze(text, n_devices=n_dev)
-    ca = hlo.cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis()
 
     # analytical comparator at the SAME geometry: the compiled module
     # always attends the full virtual span, so past_len tops kv_len up to

@@ -152,6 +152,23 @@ def get(name: Union[str, HardwareSpec]) -> HardwareSpec:
                        f" (aliases: {sorted(ALIASES)})") from None
 
 
+#: ``jax.devices()[i].device_kind`` → the registered spec of that chip.
+#: A kind not listed here has no spec: :func:`for_device_kind` raises
+#: rather than price a run on another chip's numbers.
+DEVICE_KINDS: Dict[str, str] = {
+    "TPU v5 lite": "tpu-v5e",
+}
+
+
+def for_device_kind(kind: str) -> HardwareSpec:
+    """The spec of the chip JAX reports as ``kind`` (``device_kind``)."""
+    try:
+        return REGISTRY[DEVICE_KINDS[kind]]
+    except KeyError:
+        raise KeyError(f"no hardware spec for device kind {kind!r}; known "
+                       f"kinds: {sorted(DEVICE_KINDS)}") from None
+
+
 # public registry-listing alias; kept LAST so the builtin `list` is never
 # shadowed inside this module's own code above
 list = names

@@ -540,18 +540,6 @@ def analyze(hlo_text: str, n_devices: int = 1,
                       collective_wire_elements=welems)
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across jax versions.
-
-    Older jax returns a one-element list of dicts (one per computation),
-    newer jax returns the dict directly; either may be empty/None.
-    """
-    cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 # ===========================================================================
 # Donation / buffer-aliasing introspection (compile-hygiene audits)
 # ===========================================================================

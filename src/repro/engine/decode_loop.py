@@ -35,8 +35,9 @@ Two attention read paths (``EngineConfig.attn_impl``):
 * ``"paged"``  — Pallas paged flash kernels
   (``repro.kernels.paged_attention``): K/V read block-by-block through the
   block table with online softmax, no page buffer, blocks past the cursor
-  skipped, int8 KV dequantized in-kernel.  Interpret mode on CPU keeps it
-  correct (but slow) in this container; on TPU it is the hot path.
+  skipped, int8 KV dequantized in-kernel.  On the CPU backend the kernels
+  run in Pallas interpret mode (correct but slow); on TPU they lower to
+  Mosaic.
 
 Tensor parallelism: on a mesh whose ``policy.tp_axis`` has size ``tp > 1``
 the engine runs sharded over KV heads — weights and the block-paged KV
@@ -210,6 +211,14 @@ def _staged_scan(scan_fn, x, xs, pp: int):
 # per-layer bodies against one block table / all block tables
 # ---------------------------------------------------------------------------
 
+def _pages(c, bt):
+    """Gather-path page read: ``(N, Hk, bs, hd)`` pool rows of the block
+    table(s) ``bt (..., nb)`` → ``(..., nb * bs, Hk, hd)`` contiguous
+    virtual sequence(s), token-major like the attention einsums expect."""
+    g = jnp.swapaxes(c[bt], -3, -2)             # (..., nb, bs, Hk, hd)
+    return g.reshape(*bt.shape[:-1], -1, *g.shape[-2:])
+
+
 def _channel_mix(cfg: ArchConfig, p, x):
     if "mlp" not in p:
         return x
@@ -227,7 +236,7 @@ def _prefill_layer(cfg: ArchConfig, p, x, ck, cv, bt_slot, pos_q, valid_end,
                    lora=None, lora_idx=None, lora_fn=None):
     """One layer of a single-slot prompt chunk.
 
-    x: (1, C, d); ck/cv: (N, bs, Hk, hd) full block-pool buffers of this
+    x: (1, C, d); ck/cv: (N, Hk, bs, hd) full block-pool buffers of this
     layer; bt_slot: (max_bps,) the slot's block table; pos_q: (C,)
     absolute positions of the chunk tokens; positions ``>= valid_end`` are
     padding (their K/V scatter targets block id N — out of bounds, so the
@@ -238,7 +247,7 @@ def _prefill_layer(cfg: ArchConfig, p, x, ck, cv, bt_slot, pos_q, valid_end,
     add grouped low-rank deltas on q/k/v (pre-RoPE) and the attention
     output — multi-tenant LoRA serving.
     """
-    N, bs = ck.shape[0], ck.shape[1]
+    N, bs = ck.shape[0], ck.shape[2]
     L_virt = bt_slot.shape[0] * bs
     h = apply_norm(cfg.norm_kind, x, p["ln1"])
     deltas = (None if lora is None
@@ -248,8 +257,8 @@ def _prefill_layer(cfg: ArchConfig, p, x, ck, cv, bt_slot, pos_q, valid_end,
     # scatter the chunk's K/V through the block table
     blk = jnp.where(pos_q < valid_end, bt_slot[pos_q // bs], N)
     off = pos_q % bs
-    ck = ck.at[blk, off].set(k_new[0].astype(ck.dtype))
-    cv = cv.at[blk, off].set(v_new[0].astype(cv.dtype))
+    ck = ck.at[blk, :, off].set(k_new[0].astype(ck.dtype))
+    cv = cv.at[blk, :, off].set(v_new[0].astype(cv.dtype))
     b, s = x.shape[0], x.shape[1]
     if attn_impl == "paged":
         # read K/V block-by-block through the table — no page buffer
@@ -258,8 +267,8 @@ def _prefill_layer(cfg: ArchConfig, p, x, ck, cv, bt_slot, pos_q, valid_end,
         out = out.reshape(1, s, -1)
     else:
         # gather the slot's pages back into its contiguous virtual sequence
-        page_k = ck[bt_slot].reshape(1, L_virt, *ck.shape[2:])
-        page_v = cv[bt_slot].reshape(1, L_virt, *cv.shape[2:])
+        page_k = _pages(ck, bt_slot[None])
+        page_v = _pages(cv, bt_slot[None])
         k_pos = jnp.arange(L_virt, dtype=jnp.int32)
         mask = ((k_pos[None, :] <= pos_q[:, None])
                 & (k_pos[None, :] < valid_end))[None, None, None]
@@ -281,13 +290,13 @@ def _decode_layer(cfg: ArchConfig, p, x, ck, cv, bt, pos, active,
                   lora=None, lora_idx=None, lora_fn=None):
     """One layer of a one-token step for ALL slots.
 
-    x: (S, 1, d); ck/cv: (N, bs, Hk, hd); bt: (S, max_bps) block tables;
+    x: (S, 1, d); ck/cv: (N, Hk, bs, hd); bt: (S, max_bps) block tables;
     pos: (S,) per-slot cursors; active: (S,) bool — inactive slots neither
     write KV nor advance (their scatter block id is forced out of bounds
     and dropped).  ``lora``/``lora_idx`` (S,)/``lora_fn`` apply per-slot
     grouped low-rank deltas (multi-tenant LoRA; -1 = base model).
     """
-    N, bs = ck.shape[0], ck.shape[1]
+    N, bs = ck.shape[0], ck.shape[2]
     S_, max_bps = bt.shape
     L_virt = max_bps * bs
     h = apply_norm(cfg.norm_kind, x, p["ln1"])
@@ -297,16 +306,15 @@ def _decode_layer(cfg: ArchConfig, p, x, ck, cv, bt, pos, active,
                                      deltas)
     rows = jnp.arange(S_, dtype=jnp.int32)
     blk = jnp.where(active, bt[rows, pos // bs], N)
-    ck = ck.at[blk, pos % bs].set(k_new[:, 0].astype(ck.dtype))
-    cv = cv.at[blk, pos % bs].set(v_new[:, 0].astype(cv.dtype))
+    ck = ck.at[blk, :, pos % bs].set(k_new[:, 0].astype(ck.dtype))
+    cv = cv.at[blk, :, pos % bs].set(v_new[:, 0].astype(cv.dtype))
     if attn_impl == "paged":
         # block-by-block flash decode per slot table — no page buffer,
         # blocks past each slot's cursor are skipped inside the kernel
         out = paged_fn(q[:, 0], ck, cv, bt, pos)
         out = out.reshape(S_, 1, -1)
     else:
-        page_k = ck[bt].reshape(S_, L_virt, *ck.shape[2:])
-        page_v = cv[bt].reshape(S_, L_virt, *cv.shape[2:])
+        page_k, page_v = _pages(ck, bt), _pages(cv, bt)
         k_pos = jnp.arange(L_virt, dtype=jnp.int32)
         # per-slot causal mask over its virtual sequence (keys strictly
         # before + the token just written at pos)
@@ -331,7 +339,7 @@ def _verify_layer(cfg: ArchConfig, p, x, ck, cv, bt, pos, active, valid_q,
 
     x: (S, Q, d) — slot ``s``'s queries are its pending token plus its k
     draft proposals, at absolute positions ``pos[s] .. pos[s]+Q-1``;
-    ck/cv: (N, bs, Hk, hd); bt: (S, max_bps); pos: (S,) cursors;
+    ck/cv: (N, Hk, bs, hd); bt: (S, max_bps); pos: (S,) cursors;
     valid_q: (S,) live queries per slot (budget-capped — padding queries
     neither write KV nor matter downstream; their scatter block id is
     forced out of bounds and dropped, mirroring prefill chunk padding).
@@ -342,7 +350,7 @@ def _verify_layer(cfg: ArchConfig, p, x, ck, cv, bt, pos, active, valid_q,
     Rejected candidates stay in place past the rolled-back cursor:
     unreachable under the causal mask, overwritten by the next step.
     """
-    N, bs = ck.shape[0], ck.shape[1]
+    N, bs = ck.shape[0], ck.shape[2]
     S_, max_bps = bt.shape
     Q = x.shape[1]
     L_virt = max_bps * bs
@@ -355,15 +363,14 @@ def _verify_layer(cfg: ArchConfig, p, x, ck, cv, bt, pos, active, valid_q,
     live = active[:, None] & (qi < valid_q[:, None])
     rows = jnp.arange(S_, dtype=jnp.int32)[:, None]
     blk = jnp.where(live, bt[rows, pos_q // bs], N)
-    ck = ck.at[blk, pos_q % bs].set(k_new.astype(ck.dtype))
-    cv = cv.at[blk, pos_q % bs].set(v_new.astype(cv.dtype))
+    ck = ck.at[blk, :, pos_q % bs].set(k_new.astype(ck.dtype))
+    cv = cv.at[blk, :, pos_q % bs].set(v_new.astype(cv.dtype))
     if attn_impl == "paged":
         # one batched multi-query flash pass through every slot's table
         out = paged_fn(q, ck, cv, bt, pos)
         out = out.reshape(S_, Q, -1)
     else:
-        page_k = ck[bt].reshape(S_, L_virt, *ck.shape[2:])
-        page_v = cv[bt].reshape(S_, L_virt, *cv.shape[2:])
+        page_k, page_v = _pages(ck, bt), _pages(cv, bt)
         k_pos = jnp.arange(L_virt, dtype=jnp.int32)
         # per-slot, per-query causal mask over the virtual sequence
         mask = (k_pos[None, None, :] <= pos_q[:, :, None])[:, None, None]
@@ -407,18 +414,17 @@ def make_engine_fns(cfg: ArchConfig, mesh: Mesh, policy: S.ShardingPolicy,
         # Pallas calls are opaque to GSPMD: shard them explicitly over the
         # KV-head axis — each chip runs the kernel against its own shard
         # of every cache block (no cross-chip traffic inside attention)
-        from jax.experimental.shard_map import shard_map
         tpa = policy.tp_axis
         head = P(None, tpa, None, None)      # (S|C, Hk, G, d)
-        pool = P(None, None, tpa, None)      # (N, bs, Hk, d)
-        paged_decode_fn = shard_map(
+        pool = P(None, tpa, None, None)      # (N, Hk, bs, d)
+        paged_decode_fn = jax.shard_map(
             paged_ops.paged_decode, mesh=mesh,
             in_specs=(head, pool, pool, P(None, None), P(None)),
-            out_specs=head, check_rep=False)
-        paged_prefill_fn = shard_map(
+            out_specs=head, check_vma=False)
+        paged_prefill_fn = jax.shard_map(
             paged_ops.paged_prefill, mesh=mesh,
             in_specs=(head, pool, pool, P(None), P(), P()),
-            out_specs=head, check_rep=False)
+            out_specs=head, check_vma=False)
 
     use_lora = cache.lora_slots > 0
     lora_fn = _make_lora_fn(cache, mesh, policy, attn_impl, tp)
@@ -552,14 +558,13 @@ def make_prefill_batch_fn(cfg: ArchConfig, mesh: Mesh,
 
     paged_verify_fn = paged_ops.paged_verify
     if tp > 1 and attn_impl == "paged":
-        from jax.experimental.shard_map import shard_map
         tpa = policy.tp_axis
         head = P(None, None, tpa, None, None)   # (B, C, Hk, G, d)
-        pool = P(None, None, tpa, None)         # (N, bs, Hk, d)
-        paged_verify_fn = shard_map(
+        pool = P(None, tpa, None, None)         # (N, Hk, bs, d)
+        paged_verify_fn = jax.shard_map(
             paged_ops.paged_verify, mesh=mesh,
             in_specs=(head, pool, pool, P(None, None), P(None)),
-            out_specs=head, check_rep=False)
+            out_specs=head, check_vma=False)
 
     use_lora = cache.lora_slots > 0
     lora_fn = _make_lora_fn(cache, mesh, policy, attn_impl, tp)
@@ -632,14 +637,13 @@ def make_verify_fn(cfg: ArchConfig, mesh: Mesh, policy: S.ShardingPolicy,
 
     paged_verify_fn = paged_ops.paged_verify
     if tp > 1 and attn_impl == "paged":
-        from jax.experimental.shard_map import shard_map
         tpa = policy.tp_axis
         head = P(None, None, tpa, None, None)   # (S, Q, Hk, G, d)
-        pool = P(None, None, tpa, None)         # (N, bs, Hk, d)
-        paged_verify_fn = shard_map(
+        pool = P(None, tpa, None, None)         # (N, Hk, bs, d)
+        paged_verify_fn = jax.shard_map(
             paged_ops.paged_verify, mesh=mesh,
             in_specs=(head, pool, pool, P(None, None), P(None)),
-            out_specs=head, check_rep=False)
+            out_specs=head, check_vma=False)
 
     use_lora = cache.lora_slots > 0
     lora_fn = _make_lora_fn(cache, mesh, policy, attn_impl, tp)

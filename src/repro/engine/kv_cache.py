@@ -4,7 +4,11 @@ KV storage is one global pool of ``n_blocks`` fixed-size blocks of
 ``block_size`` token positions, in preallocated, sharded cache buffers
 shaped
 
-    (n_attn_layers, n_blocks, block_size, n_kv_heads, head_dim)
+    (n_attn_layers, n_blocks, n_kv_heads, block_size, head_dim)
+
+Head-major within a block, so each (block, head) pair is one contiguous
+``(block_size, head_dim)`` tile: the shape a Pallas TPU kernel can DMA
+block-by-block through the table (``repro.kernels.paged_attention``).
 
 Each of ``max_slots`` concurrent requests owns a *block table* — a row of
 physical block ids whose concatenation is the request's virtual KV
@@ -103,8 +107,8 @@ class BlockPagedKVCache:
 
     def buffer_shape(self):
         c = self.cfg
-        return (c.n_layers, self.n_blocks, self.block_size,
-                c.n_kv_heads, c.head_dim)
+        return (c.n_layers, self.n_blocks, c.n_kv_heads,
+                self.block_size, c.head_dim)
 
     def init_state(self) -> Dict[str, jax.Array]:
         """Fresh engine device state: empty block pool + per-slot tables."""
@@ -158,8 +162,8 @@ class BlockPagedKVCache:
         # No kv_len fallback here: intra-block token sharding would split
         # scatter targets across chips for zero capacity win.
         axes = {
-            "cache_k": ("layers", None, None, "kv_heads", None),
-            "cache_v": ("layers", None, None, "kv_heads", None),
+            "cache_k": ("layers", None, "kv_heads", None, None),
+            "cache_v": ("layers", None, "kv_heads", None, None),
             "block_tables": ("batch", None),
             "pos": ("batch",),
             "tok": ("batch",),

@@ -303,10 +303,11 @@ class Engine:
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_steps = 0
-        # commit the fresh state to its serving shardings up front:
-        # otherwise the first jitted step sees uncommitted inputs and
-        # compiles a second executable once its (committed) outputs feed
-        # the next call — every entry point would compile twice
+        # commit params and the fresh state to their serving shardings up
+        # front: params placed elsewhere would be moved on every call, and
+        # uncommitted state makes the first jitted step compile a second
+        # executable once its (committed) outputs feed the next call
+        self.params = jax.device_put(params, self.shardings["params"])
         self.state = jax.device_put(self.cache.init_state(),
                                     self.shardings["state"])
         self._rng = jax.random.PRNGKey(ec.seed)

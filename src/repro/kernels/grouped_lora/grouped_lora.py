@@ -27,7 +27,12 @@ Kernel shape (mirrors ``repro.kernels.paged_attention``):
   contribute exact zeros — raggedness costs pad-lane MXU throughput,
   never correctness;
 * the two dots accumulate in f32 (``preferred_element_type``) and cast
-  back to the activation dtype on the way out.
+  back to the activation dtype on the way out;
+* the output columns stream in lane tiles (a second grid axis): ``x @ A``
+  is computed once per slot into VMEM scratch and each tile of ``B`` is
+  applied to it, so VMEM holds a ``(T, tile)`` output block rather than
+  the whole ``(T, n)`` row — an MLP-width ``n`` at a prefill-chunk ``T``
+  would otherwise overrun the TPU's scoped VMEM.
 
 Tensor parallelism shards the *rank* axis: A column- and B
 row-partitioned (see ``ops.make_sharded_grouped_lora``), each chip
@@ -45,18 +50,31 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _grouped_lora_kernel(idx_ref, x_ref, a_ref, b_ref, o_ref, *,
+def _n_tile(n: int) -> int:
+    """Output-column tile: the largest of 512/256/128 lanes dividing
+    ``n`` (else all of ``n``), so a ``(T, n)`` output of any projection
+    width streams through VMEM a tile at a time."""
+    return next((t for t in (512, 256, 128) if n % t == 0), n)
+
+
+def _grouped_lora_kernel(idx_ref, x_ref, a_ref, b_ref, o_ref, xa_ref, *,
                          scale: float):
     s = pl.program_id(0)
+    j = pl.program_id(1)
 
-    @pl.when(idx_ref[s] >= 0)
-    def _apply():
+    @pl.when((idx_ref[s] >= 0) & (j == 0))
+    def _down():
+        # x @ A once per slot; every output-column tile reuses it
         x = x_ref[0].astype(jnp.float32)           # (T, k)
         a = a_ref[0].astype(jnp.float32)           # (k, R) — padded rank
-        b = b_ref[0].astype(jnp.float32)           # (R, n)
-        xa = jax.lax.dot_general(x, a, (((1,), (0,)), ((), ())),
-                                 preferred_element_type=jnp.float32)
-        o = jax.lax.dot_general(xa, b, (((1,), (0,)), ((), ())),
+        xa_ref[...] = jax.lax.dot_general(
+            x, a, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    @pl.when(idx_ref[s] >= 0)
+    def _up():
+        b = b_ref[0].astype(jnp.float32)           # (R, tn)
+        o = jax.lax.dot_general(xa_ref[...], b, (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         o_ref[0] = (scale * o).astype(o_ref.dtype)
 
@@ -85,22 +103,24 @@ def grouped_lora_fwd(
     if k2 != k or P2 != P or R2 != R:
         raise ValueError(f"inconsistent grouped-LoRA operands: x {x.shape}, "
                          f"A {A.shape}, B {B.shape}")
+    tn = _n_tile(n)
     kernel = functools.partial(_grouped_lora_kernel, scale=scale)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(S,),
+        grid=(S, n // tn),
         in_specs=[
-            pl.BlockSpec((1, T, k), lambda s, idx_ref: (s, 0, 0)),
+            pl.BlockSpec((1, T, k), lambda s, j, idx_ref: (s, 0, 0)),
             # clamp -1 ("no adapter") to slot 0: the DMA must target a
             # real block; the kernel body skips the compute either way
             pl.BlockSpec((1, k, R),
-                         lambda s, idx_ref: (jnp.maximum(idx_ref[s], 0),
-                                             0, 0)),
-            pl.BlockSpec((1, R, n),
-                         lambda s, idx_ref: (jnp.maximum(idx_ref[s], 0),
-                                             0, 0)),
+                         lambda s, j, idx_ref: (jnp.maximum(idx_ref[s], 0),
+                                                0, 0)),
+            pl.BlockSpec((1, R, tn),
+                         lambda s, j, idx_ref: (jnp.maximum(idx_ref[s], 0),
+                                                0, j)),
         ],
-        out_specs=pl.BlockSpec((1, T, n), lambda s, idx_ref: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, T, tn), lambda s, j, idx_ref: (s, 0, j)),
+        scratch_shapes=[pltpu.VMEM((T, R), jnp.float32)],   # x @ A
     )
     return pl.pallas_call(
         kernel, grid_spec=grid_spec,

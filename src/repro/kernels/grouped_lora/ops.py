@@ -51,17 +51,15 @@ def make_sharded_grouped_lora(mesh: Mesh, tp_axis: str, *,
     adapter (A columns / B rows) and the partial deltas are ``psum``-med
     — requires the padded pool rank to be divisible by the axis size.
     """
-    from jax.experimental.shard_map import shard_map
-
     def _local(x, A, B, idx):
         part = grouped_lora(x, A, B, idx, scale=scale)
         return jax.lax.psum(part, tp_axis)
 
-    return shard_map(
+    return jax.shard_map(
         _local, mesh=mesh,
         in_specs=(P(None, None, None), P(None, None, tp_axis),
                   P(None, tp_axis, None), P(None)),
-        out_specs=P(None, None, None), check_rep=False)
+        out_specs=P(None, None, None), check_vma=False)
 
 
 __all__ = ["grouped_lora", "grouped_lora_pregathered", "grouped_lora_ref",

@@ -27,7 +27,7 @@ def paged_decode(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                  interpret: Optional[bool] = None) -> jax.Array:
     """Paged flash decode: one query token per slot against its table.
 
-    q: (S, Hk, G, d); caches: (N, bs, Hk, d); tables: (S, max_bps) int32;
+    q: (S, Hk, G, d); caches: (N, Hk, bs, d); tables: (S, max_bps) int32;
     pos: (S,) cursors — the key at ``pos[s]`` is the newest attended.
     """
     if interpret is None:
@@ -60,7 +60,7 @@ def paged_verify(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
 
     q: (S, Q, Hk, G, d) — slot ``s``'s queries sit at absolute positions
     ``pos[s] .. pos[s]+Q-1`` (the pending token plus k=Q-1 drafts, whose
-    K/V were scattered before this call); caches: (N, bs, Hk, d);
+    K/V were scattered before this call); caches: (N, Hk, bs, d);
     tables: (S, max_bps) int32; pos: (S,) cursors.
     """
     if interpret is None:

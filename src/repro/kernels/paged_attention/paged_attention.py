@@ -1,7 +1,8 @@
 """Paged flash attention Pallas TPU kernels (decode + chunked prefill).
 
-The serving engine stores KV in a global block pool ``(N, bs, Hk, hd)``
-addressed through per-slot block tables.  The XLA engine path gathers each
+The serving engine stores KV in a global head-major block pool
+``(N, Hk, bs, hd)`` addressed through per-slot block tables.  The XLA
+engine path gathers each
 slot's blocks back into a contiguous ``(L_virt, Hk, hd)`` page buffer per
 layer per step — exactly the HBM materialization the paper's canonical
 fusion example (flash attention, §3.2.1) exists to elide.  These kernels
@@ -16,6 +17,12 @@ read K/V *block-by-block through the block table* with online softmax:
   work — the gather path pays for the full virtual width);
 * int8 KV dequantizes in-kernel (``astype`` on the VMEM-resident block),
   matching the engine's cast-based KV compression (§3.3.3).
+
+The pool is head-major so that one grid step's K/V block is
+``(1, 1, bs, hd)``: its last two dimensions are the whole ``(bs, hd)``
+tile of the array, which Mosaic accepts for any ``bs``.  A token-major
+pool ``(N, bs, Hk, hd)`` would need a ``(1, bs, 1, hd)`` block, whose
+second-minor dimension (one of ``Hk`` heads) the TPU compiler refuses.
 
 The KV-block grid dimension is minor-most so the VMEM accumulators
 persist across KV steps (sequential grid execution on TPU; see
@@ -54,8 +61,8 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ki * bs <= pos)
     def _compute():
         q = q_ref[0, 0].astype(jnp.float32)        # (G, d)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bs, d) — int8 KV
-        v = v_ref[0, :, 0, :].astype(jnp.float32)  # dequantizes right here
+        k = k_ref[0, 0].astype(jnp.float32)   # (bs, d) — int8 KV
+        v = v_ref[0, 0].astype(jnp.float32)   # dequantizes right here
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         k_pos = ki * bs + jax.lax.broadcasted_iota(jnp.int32, sc.shape, 1)
@@ -79,15 +86,15 @@ def _decode_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_decode_fwd(
     q: jax.Array,            # (S, Hk, G, d) one query token per slot
-    cache_k: jax.Array,      # (N, bs, Hk, d) global block pool
-    cache_v: jax.Array,      # (N, bs, Hk, d)
+    cache_k: jax.Array,      # (N, Hk, bs, d) global block pool
+    cache_v: jax.Array,      # (N, Hk, bs, d)
     block_tables: jax.Array,  # (S, max_bps) int32 physical block ids
     pos: jax.Array,          # (S,) int32 cursors (key at ``pos`` is newest)
     *,
     interpret: bool = False,
 ) -> jax.Array:
     S, Hk, G, d = q.shape
-    bs = cache_k.shape[1]
+    bs = cache_k.shape[2]
     nb = block_tables.shape[1]
     kernel = functools.partial(_decode_kernel, bs=bs, n_blocks=nb,
                                scale=d ** -0.5)
@@ -97,10 +104,10 @@ def paged_decode_fwd(
         in_specs=[
             pl.BlockSpec((1, 1, G, d),
                          lambda s, h, ki, bt, ps: (s, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s, h, ki, bt, ps: (bt[s, ki], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s, h, ki, bt, ps: (bt[s, ki], 0, h, 0)),
+            pl.BlockSpec((1, 1, bs, d),
+                         lambda s, h, ki, bt, ps: (bt[s, ki], h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, d),
+                         lambda s, h, ki, bt, ps: (bt[s, ki], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, G, d),
                                lambda s, h, ki, bt, ps: (s, h, 0, 0)),
@@ -137,8 +144,8 @@ def _prefill_kernel(bt_ref, span_ref, q_ref, k_ref, v_ref, o_ref,
     def _compute():
         C = q_ref.shape[0]
         q = q_ref[:, 0].astype(jnp.float32).reshape(C * group, -1)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # (bs, d)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)   # (bs, d)
+        v = v_ref[0, 0].astype(jnp.float32)
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         # row r holds query position start + r // group (grouped heads are
@@ -169,8 +176,8 @@ def _prefill_kernel(bt_ref, span_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_prefill_fwd(
     q: jax.Array,            # (C, Hk, G, d) one prompt chunk of one slot
-    cache_k: jax.Array,      # (N, bs, Hk, d) global block pool
-    cache_v: jax.Array,      # (N, bs, Hk, d)
+    cache_k: jax.Array,      # (N, Hk, bs, d) global block pool
+    cache_v: jax.Array,      # (N, Hk, bs, d)
     block_table: jax.Array,  # (max_bps,) int32 — the slot's table
     start: jax.Array,        # scalar: absolute position of q[0]
     valid: jax.Array,        # scalar: valid chunk tokens (tail is padding)
@@ -179,7 +186,7 @@ def paged_prefill_fwd(
 ) -> jax.Array:
     """See :func:`paged_verify_fwd` for the multi-slot q_len>1 variant."""
     C, Hk, G, d = q.shape
-    bs = cache_k.shape[1]
+    bs = cache_k.shape[2]
     nb = block_table.shape[0]
     span = jnp.stack([jnp.asarray(start, jnp.int32),
                       jnp.asarray(start + valid, jnp.int32)])
@@ -190,10 +197,10 @@ def paged_prefill_fwd(
         grid=(Hk, nb),
         in_specs=[
             pl.BlockSpec((C, 1, G, d), lambda h, ki, bt, sp: (0, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda h, ki, bt, sp: (bt[ki], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda h, ki, bt, sp: (bt[ki], 0, h, 0)),
+            pl.BlockSpec((1, 1, bs, d),
+                         lambda h, ki, bt, sp: (bt[ki], h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, d),
+                         lambda h, ki, bt, sp: (bt[ki], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((C, 1, G, d),
                                lambda h, ki, bt, sp: (0, h, 0, 0)),
@@ -232,8 +239,8 @@ def _verify_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
     def _compute():
         Q = q_ref.shape[1]
         q = q_ref[0].astype(jnp.float32).reshape(Q * group, -1)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)   # (bs, d) — int8 KV
-        v = v_ref[0, :, 0, :].astype(jnp.float32)   # dequantizes right here
+        k = k_ref[0, 0].astype(jnp.float32)   # (bs, d) — int8 KV
+        v = v_ref[0, 0].astype(jnp.float32)   # dequantizes right here
         sc = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         # row r holds query position pos + r // group (grouped heads
@@ -265,8 +272,8 @@ def _verify_kernel(bt_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
 def paged_verify_fwd(
     q: jax.Array,            # (S, Q, Hk, G, d) Q=k+1 query tokens per slot
-    cache_k: jax.Array,      # (N, bs, Hk, d) global block pool
-    cache_v: jax.Array,      # (N, bs, Hk, d)
+    cache_k: jax.Array,      # (N, Hk, bs, d) global block pool
+    cache_v: jax.Array,      # (N, Hk, bs, d)
     block_tables: jax.Array,  # (S, max_bps) int32 physical block ids
     pos: jax.Array,          # (S,) int32 cursors (query i is at pos+i)
     *,
@@ -282,7 +289,7 @@ def paged_verify_fwd(
     KV blocks entirely past a slot's candidate span are skipped.
     """
     S, Q, Hk, G, d = q.shape
-    bs = cache_k.shape[1]
+    bs = cache_k.shape[2]
     nb = block_tables.shape[1]
     kernel = functools.partial(_verify_kernel, bs=bs, n_blocks=nb,
                                group=G, scale=d ** -0.5)
@@ -292,10 +299,10 @@ def paged_verify_fwd(
         in_specs=[
             pl.BlockSpec((1, Q, 1, G, d),
                          lambda s, h, ki, bt, ps: (s, 0, h, 0, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s, h, ki, bt, ps: (bt[s, ki], 0, h, 0)),
-            pl.BlockSpec((1, bs, 1, d),
-                         lambda s, h, ki, bt, ps: (bt[s, ki], 0, h, 0)),
+            pl.BlockSpec((1, 1, bs, d),
+                         lambda s, h, ki, bt, ps: (bt[s, ki], h, 0, 0)),
+            pl.BlockSpec((1, 1, bs, d),
+                         lambda s, h, ki, bt, ps: (bt[s, ki], h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, Q, 1, G, d),
                                lambda s, h, ki, bt, ps: (s, 0, h, 0, 0)),

@@ -12,20 +12,20 @@ import jax.numpy as jnp
 
 
 def _gather_pages(cache, table):
-    """(N, bs, Hk, d)[table] -> (L_virt, Hk, d) contiguous virtual page."""
-    bs = cache.shape[1]
-    return cache[table].reshape(table.shape[0] * bs, *cache.shape[2:])
+    """(N, Hk, bs, d)[table] -> (L_virt, Hk, d) contiguous virtual page."""
+    nb, (Hk, bs, d) = table.shape[0], cache.shape[1:]
+    return cache[table].transpose(0, 2, 1, 3).reshape(nb * bs, Hk, d)
 
 
 def paged_decode_ref(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                      block_tables: jax.Array, pos: jax.Array) -> jax.Array:
-    """q: (S, Hk, G, d); caches: (N, bs, Hk, d); tables: (S, nb); pos: (S,).
+    """q: (S, Hk, G, d); caches: (N, Hk, bs, d); tables: (S, nb); pos: (S,).
 
     Each slot attends its one query token over keys ``[0, pos[s]]`` of its
     gathered virtual sequence.
     """
     S, Hk, G, d = q.shape
-    L = block_tables.shape[1] * cache_k.shape[1]
+    L = block_tables.shape[1] * cache_k.shape[2]
     k_pos = jnp.arange(L, dtype=jnp.int32)
 
     def one_slot(qs, table, p):
@@ -42,7 +42,7 @@ def paged_decode_ref(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
 
 def paged_verify_ref(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
                      block_tables: jax.Array, pos: jax.Array) -> jax.Array:
-    """q: (S, Q, Hk, G, d); caches: (N, bs, Hk, d); tables: (S, nb);
+    """q: (S, Q, Hk, G, d); caches: (N, Hk, bs, d); tables: (S, nb);
     pos: (S,).
 
     Speculative verify semantics: slot ``s``'s query ``i`` sits at
@@ -52,7 +52,7 @@ def paged_verify_ref(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     chunk's own tokens).
     """
     S, Q, Hk, G, d = q.shape
-    L = block_tables.shape[1] * cache_k.shape[1]
+    L = block_tables.shape[1] * cache_k.shape[2]
     k_pos = jnp.arange(L, dtype=jnp.int32)
 
     def one_slot(qs, table, p):
@@ -77,7 +77,7 @@ def paged_prefill_ref(q: jax.Array, cache_k: jax.Array, cache_v: jax.Array,
     (causally masked); chunk rows past ``valid`` are padding (garbage out).
     """
     C, Hk, G, d = q.shape
-    L = block_table.shape[0] * cache_k.shape[1]
+    L = block_table.shape[0] * cache_k.shape[2]
     pk = _gather_pages(cache_k, block_table).astype(jnp.float32)
     pv = _gather_pages(cache_v, block_table).astype(jnp.float32)
     sc = jnp.einsum("skgd,lkd->skgl", q.astype(jnp.float32), pk) * d ** -0.5

@@ -66,7 +66,7 @@ def run_cell(arch: str, shape: str, *, multi_pod: bool = False,
             compiled = lowered.compile()
             t_compile = time.time() - t0 - t_lower
 
-            cost = hlo_mod.cost_analysis_dict(compiled)
+            cost = compiled.cost_analysis()
             mem = compiled.memory_analysis()
             hlo_text = compiled.as_text()
             # loop-folded per-chip cost (cost_analysis counts while bodies

@@ -9,7 +9,6 @@ tensor-parallel CPU smoke runs) request them via
 from __future__ import annotations
 
 import os
-import re
 
 import jax
 
@@ -19,36 +18,20 @@ def ensure_host_device_count(n: int) -> bool:
 
     Appends ``--xla_force_host_platform_device_count=n`` to ``XLA_FLAGS``
     *preserving any flags already set*; an existing device-count flag is
-    respected as-is (the caller pinned it deliberately).  A no-op once JAX
-    has initialized its backends — the count is locked at first device
-    use.  Returns True when ≥ ``n`` devices are (or will be) visible.
+    respected as-is (the caller pinned it deliberately).  The count is
+    locked when JAX first initializes its backends, so call this before
+    any device use.  Returns True when ≥ ``n`` devices are visible.
     """
-    try:
-        from jax._src import xla_bridge
-        initialized = bool(xla_bridge._backends)
-    except Exception:           # private API moved: assume initialized
-        initialized = True
-    if initialized:
-        return jax.device_count() >= n
     flags = os.environ.get("XLA_FLAGS", "")
-    pinned = re.search(r"--xla_force_host_platform_device_count=(\d+)",
-                       flags)
-    if pinned:                  # respect the explicit setting
-        return int(pinned.group(1)) >= n
-    sep = " " if flags else ""
-    os.environ["XLA_FLAGS"] = (
-        f"{flags}{sep}--xla_force_host_platform_device_count={n}")
-    return True
+    if "--xla_force_host_platform_device_count=" not in flags:
+        os.environ["XLA_FLAGS"] = (
+            f"{flags} --xla_force_host_platform_device_count={n}".strip())
+    return jax.device_count() >= n
 
 
 def _make_mesh(shape, axes):
-    # jax.sharding.AxisType appeared after 0.4.x; older runtimes default to
-    # Auto axes already, so only pass axis_types when the API exists.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -8,17 +8,18 @@ Runs the continuous-batching engine (slot-paged KV cache, chunked-prefill
 admission, fused decode blocks) over a synthetic request stream, then
 replays the scheduler's own trace through the analytical twin to print
 forecast TTFT/TPOT/TPS for the TARGET hardware (TPU v5e) next to the
-measured host-CPU wall-clock — the paper's forecast-vs-measured loop for
-multi-request traffic.
+measured wall-clock — the paper's forecast-vs-measured loop for
+multi-request traffic.  The model runs tensor-parallel over every device
+present (a ``model=N`` mesh): one v5e chip holds qwen2-7b's weights, a
+four-chip host splits them four ways.
 
 ``--legacy`` keeps the old single-shot lockstep ``Server`` path (also the
 only path for engine-unsupported families: ssm / hybrid / encdec / MLA).
 
-NOTE: for the common cases (no mesh/sharding control needed) prefer the
-unified front door — ``python -m repro {forecast,measure,sweep,compare}``
-(``repro.api``).  This launcher remains for production mesh layouts and
-multi-pod sharding; its single-request orientation forecast is itself
-served by ``repro.api`` now.
+NOTE: for the common cases prefer the unified front door —
+``python -m repro {forecast,measure,sweep,compare}`` (``repro.api``); this
+launcher's single-request orientation forecast is itself served by
+``repro.api``.
 """
 from __future__ import annotations
 
@@ -36,13 +37,13 @@ from repro.engine import (Engine, EngineConfig, ForecastTwin, Request,
                           engine_supported)
 from repro.models import init_params
 from repro.runtime import ShardingPolicy, Server, ServeConfig
-from repro.launch.mesh import make_production_mesh, make_host_mesh
+from repro.runtime.sharding import param_shardings
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_host_mesh
 
 
-def run_legacy(args, cfg, mesh, params) -> None:
+def run_legacy(args, cfg, mesh, policy, params) -> None:
     max_len = args.max_len or (args.prompt_len + args.new_tokens + 16)
-    policy = ShardingPolicy(
-        dp_axes=tuple(a for a in ("pod", "data") if a in mesh.shape))
     sc = ServeConfig(batch=args.batch, max_len=max_len,
                      chunk_size=args.chunk or None, kv_dtype=args.kv_dtype,
                      temperature=args.temperature)
@@ -63,10 +64,8 @@ def run_legacy(args, cfg, mesh, params) -> None:
         **stats}, indent=1))
 
 
-def run_engine(args, cfg, full_cfg, mesh, params) -> None:
+def run_engine(args, cfg, full_cfg, mesh, policy, params) -> None:
     max_len = args.max_len or (args.prompt_len + args.new_tokens + 16)
-    policy = ShardingPolicy(
-        dp_axes=tuple(a for a in ("pod", "data") if a in mesh.shape))
     ec = EngineConfig(max_slots=args.max_slots, max_len=max_len,
                       chunk_size=args.chunk or args.prompt_len,
                       decode_block=args.decode_block,
@@ -118,13 +117,13 @@ def main() -> None:
     p.add_argument("--kv-dtype", default="bf16", choices=["bf16", "int8"])
     p.add_argument("--chunk", type=int, default=0, help="chunked prefill size")
     p.add_argument("--temperature", type=float, default=0.0)
-    p.add_argument("--multi-pod", action="store_true")
     args = p.parse_args()
+    enable_compile_cache()
 
     full_cfg = configs.get(args.arch)
     cfg = configs.reduced(full_cfg) if args.reduced else full_cfg
-    mesh = make_host_mesh() if args.reduced else make_production_mesh(
-        multi_pod=args.multi_pod)
+    mesh = make_host_mesh(model=jax.device_count())
+    policy = ShardingPolicy()
 
     # single-request LIFE forecast (paper Eqs. 1-6) for orientation
     variant = Variant(kv_dtype=args.kv_dtype, fused=True)
@@ -136,14 +135,15 @@ def main() -> None:
           f"TTFT={r.ttft_s*1e3:.1f}ms ({r.ttft_bound}-bound)  "
           f"TPOT={r.tpot_s*1e3:.2f}ms  TPS={r.tps:.1f} (1 chip, em=0.8)")
 
-    params = init_params(cfg, jax.random.PRNGKey(0))
+    params = init_params(cfg, jax.random.PRNGKey(0),
+                         param_shardings(cfg, mesh, policy))
     if args.legacy or not engine_supported(cfg):
         if not args.legacy:
             print(f"({cfg.name}: family not engine-supported; "
                   f"using legacy lockstep path)")
-        run_legacy(args, cfg, mesh, params)
+        run_legacy(args, cfg, mesh, policy, params)
     else:
-        run_engine(args, cfg, full_cfg, mesh, params)
+        run_engine(args, cfg, full_cfg, mesh, policy, params)
 
 
 if __name__ == "__main__":

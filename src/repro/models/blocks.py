@@ -180,7 +180,6 @@ def _moe_forward_a2a(cfg, p, x, capacity_factor):
             or mesh.shape[tp] == 1):
         return _moe_forward_local(cfg, p, x, capacity_factor)
     from jax.sharding import PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
 
     b, s, d = x.shape
     weights, sel, aux = _router(cfg, p, x)        # (b, s, k)
@@ -220,11 +219,11 @@ def _moe_forward_a2a(cfg, p, x, capacity_factor):
              * wts_l[..., None].astype(y_rep.dtype)).sum(axis=2)
         return jax.lax.psum(y, tp)                       # bf16 token exchange
 
-    y = shard_map(
+    y = jax.shard_map(
         expert_chunk, mesh=mesh,
         in_specs=(P(dp), P(tp), P(tp), P(tp), P(dp), P(dp), P(dp)),
         out_specs=P(dp),
-        check_rep=False,
+        check_vma=False,
     )(buf, pe["w_gate"], pe["w_up"], pe["w_down"], flat_e, pos_in_e, weights)
 
     if "shared_gate" in p:

@@ -86,8 +86,14 @@ def param_defs(cfg: ArchConfig) -> Dict:
     return defs
 
 
-def init_params(cfg: ArchConfig, rng: jax.Array) -> Dict:
-    return materialize(param_defs(cfg), rng)
+def init_params(cfg: ArchConfig, rng: jax.Array, shardings=None) -> Dict:
+    """Random parameters from ``rng``, generated by one jitted program.
+
+    ``shardings`` (a tree like ``runtime.sharding.param_shardings``)
+    places each leaf as it is made, so a model larger than one device
+    never has to exist whole on the default device."""
+    make = functools.partial(materialize, param_defs(cfg))
+    return jax.jit(make, out_shardings=shardings)(rng)
 
 
 def abstract_params(cfg: ArchConfig) -> Dict:

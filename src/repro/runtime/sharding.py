@@ -109,9 +109,7 @@ def spec_for(axes: Tuple[Optional[str], ...], shape: Tuple[int, ...],
                     continue
                 if shape[i] % _axis_size(mesh, cand) != 0:
                     continue
-                # normalize 1-tuples to the bare axis name (older jax does
-                # not equate P(("data",)) with P("data"))
-                out[i] = flat[0] if len(flat) == 1 else cand
+                out[i] = cand
                 used.update(flat)
                 break
     return P(*out)

@@ -5,6 +5,7 @@ import json
 import os
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
@@ -416,3 +417,48 @@ def test_benchmarks_run_rejects_unknown_module():
                        env=env, cwd=ROOT)
     assert r.returncode == 2
     assert "unknown benchmark module" in r.stderr
+
+
+_CACHE_PROBE = """
+import jax, jax.numpy as jnp
+from jax import monitoring
+from repro.launch.compile_cache import enable_compile_cache
+hits = []
+monitoring.register_event_listener(
+    lambda ev, **_: hits.append(ev)
+    if ev == "/jax/compilation_cache/cache_hits" else None)
+print(enable_compile_cache())
+print(jax.config.jax_compilation_cache_dir)
+jax.jit(lambda x: jnp.sin(x) @ x.T)(jnp.ones((64, 64))).block_until_ready()
+print(len(hits))
+"""
+
+
+def _cache_probe(env_dir):
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(ROOT, "src")
+    env["JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS"] = "0"
+    env["JAX_COMPILATION_CACHE_DIR"] = str(env_dir)
+    r = subprocess.run([sys.executable, "-c", _CACHE_PROBE],
+                       capture_output=True, text=True, timeout=120,
+                       env=env, cwd=ROOT)
+    assert r.returncode == 0, r.stderr[-2000:]
+    return r.stdout.split()
+
+
+def test_compile_cache_honours_env_dir_and_hits_on_rerun(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set the helper sets no other
+    directory, the cache lands there, and a second identical process
+    loads from it."""
+    first = _cache_probe(tmp_path)
+    assert first[:2] == [str(tmp_path), str(tmp_path)]
+    assert any(tmp_path.iterdir())
+    assert int(first[2]) == 0
+    assert int(_cache_probe(tmp_path)[2]) >= 1
+
+
+def test_compile_cache_defaults_to_a_fixed_dir_in_the_checkout():
+    from repro.launch import compile_cache
+    assert compile_cache.CACHE_DIR == Path(ROOT) / ".jax_cache"
+    ignored = (Path(ROOT) / ".gitignore").read_text().split()
+    assert ".jax_cache/" in ignored

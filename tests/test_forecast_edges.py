@@ -147,3 +147,11 @@ def test_twin_decode_memoization_bit_for_bit():
     # ...but equal sums with different table-entry totals do not:
     # (15, 17) reads 1+2 block-table entries, (16, 16) reads 2+2
     assert memo._decode_memo_key((15, 17)) != memo._decode_memo_key((16, 16))
+
+
+def test_device_kind_table_names_v5e_and_rejects_unknown_kinds():
+    """Forecasts next to a chip run look the chip up by ``device_kind``;
+    a kind with no spec is an error, never a silent default."""
+    assert hardware.for_device_kind("TPU v5 lite") is hardware.TPU_V5E
+    with pytest.raises(KeyError, match="device kind"):
+        hardware.for_device_kind("cpu")

@@ -18,21 +18,38 @@ from repro.launch.mesh import make_host_mesh
 from repro.models import init_params
 from repro.runtime import ShardingPolicy
 
-RNG = np.random.default_rng(7)
+KV_CODES = {jnp.float32: 0, jnp.bfloat16: 1, jnp.int8: 2}
 
 
-def _pool(N, bs, Hk, d, kv_dtype):
+def _rng(*ints):
+    """A generator seeded by the test case itself, so each case draws the
+    same data whatever order (or worker) the cases run in."""
+    return np.random.default_rng([int(i) for i in ints])
+
+
+def _pool(rng, N, bs, Hk, d, kv_dtype):
+    """Head-major K/V pools ``(N, Hk, bs, d)``, as the engine stores them."""
+    shape = (N, Hk, bs, d)
     if kv_dtype == jnp.int8:
-        ck = jnp.asarray(RNG.integers(-40, 40, (N, bs, Hk, d)), kv_dtype)
-        cv = jnp.asarray(RNG.integers(-40, 40, (N, bs, Hk, d)), kv_dtype)
+        ck = jnp.asarray(rng.integers(-40, 40, shape), kv_dtype)
+        cv = jnp.asarray(rng.integers(-40, 40, shape), kv_dtype)
     else:
-        ck = jnp.asarray(RNG.standard_normal((N, bs, Hk, d)), kv_dtype)
-        cv = jnp.asarray(RNG.standard_normal((N, bs, Hk, d)), kv_dtype)
+        ck = jnp.asarray(rng.standard_normal(shape), kv_dtype)
+        cv = jnp.asarray(rng.standard_normal(shape), kv_dtype)
     return ck, cv
 
 
 def _tol(kv_dtype):
-    return 2e-2 if kv_dtype == jnp.bfloat16 else 1e-4
+    """Absolute tolerance of kernel vs gather oracle.
+
+    Both sides compute in f32 but in a different order (online softmax
+    over blocks vs one softmax over the gathered span).  With int8 K/V in
+    ±40 the scores reach ~1e2, where one f32 rounding is ~1e-5; that
+    shifts each softmax weight by ~1e-5 relative, and values up to 40
+    turn it into ~1e-3 absolute (the worst seen over 36 seeded prefill
+    draws was 2.7e-4).  bf16 pools hold the oracle to bf16 resolution.
+    """
+    return {jnp.float32: 1e-4, jnp.bfloat16: 2e-2, jnp.int8: 1e-3}[kv_dtype]
 
 
 # ---------------------------------------------------------------------------
@@ -52,9 +69,10 @@ DECODE_CASES = [
 @pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
 def test_paged_decode_matches_gather_ref(case, kv_dtype):
     S, Hk, G, d, N, bs, nb, cursors = case
-    q = jnp.asarray(RNG.standard_normal((S, Hk, G, d)), jnp.float32)
-    ck, cv = _pool(N, bs, Hk, d, kv_dtype)
-    bt = jnp.asarray(RNG.permutation(N)[:S * nb].reshape(S, nb), jnp.int32)
+    rng = _rng(S, Hk, G, d, N, bs, nb, *cursors, KV_CODES[kv_dtype])
+    q = jnp.asarray(rng.standard_normal((S, Hk, G, d)), jnp.float32)
+    ck, cv = _pool(rng, N, bs, Hk, d, kv_dtype)
+    bt = jnp.asarray(rng.permutation(N)[:S * nb].reshape(S, nb), jnp.int32)
     pos = jnp.asarray(cursors, jnp.int32)
     out = paged_decode(q, ck, cv, bt, pos)
     ref = paged_decode_ref(q, ck, cv, bt, pos)
@@ -70,9 +88,10 @@ def test_paged_prefill_matches_gather_ref(kv_dtype, start, valid):
     on top of cached history, and a small tail remainder chunk."""
     C, Hk, G, d = 16, 2, 2, 32
     N, bs, nb = 16, 8, 5
-    q = jnp.asarray(RNG.standard_normal((C, Hk, G, d)), jnp.float32)
-    ck, cv = _pool(N, bs, Hk, d, kv_dtype)
-    table = jnp.asarray(RNG.permutation(N)[:nb], jnp.int32)
+    rng = _rng(start, valid, KV_CODES[kv_dtype])
+    q = jnp.asarray(rng.standard_normal((C, Hk, G, d)), jnp.float32)
+    ck, cv = _pool(rng, N, bs, Hk, d, kv_dtype)
+    table = jnp.asarray(rng.permutation(N)[:nb], jnp.int32)
     out = paged_prefill(q, ck, cv, table, jnp.int32(start), jnp.int32(valid))
     ref = paged_prefill_ref(q, ck, cv, table, start, valid)
     np.testing.assert_allclose(np.asarray(out[:valid], np.float32),
@@ -87,7 +106,8 @@ def test_paged_decode_shared_prefix_and_cow_tables():
     gather."""
     Hk, G, d, bs, nb = 2, 2, 32, 8, 4
     N = 12
-    ck, cv = _pool(N, bs, Hk, d, jnp.bfloat16)
+    rng = _rng(N, bs, nb)
+    ck, cv = _pool(rng, N, bs, Hk, d, jnp.bfloat16)
     shared = [0, 1]                           # full shared prefix blocks
     bt = jnp.asarray([shared + [2, 3],        # first-comer
                       shared + [4, 5],        # prefix hit, own suffix
@@ -96,7 +116,7 @@ def test_paged_decode_shared_prefix_and_cow_tables():
     # the fork duplicates the shared block before diverging mid-block
     ck = ck.at[6].set(ck[1])
     cv = cv.at[6].set(cv[1])
-    q = jnp.asarray(RNG.standard_normal((3, Hk, G, d)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((3, Hk, G, d)), jnp.float32)
     pos = jnp.asarray([25, 20, 12], jnp.int32)   # mid-block cursors
     out = paged_decode(q, ck, cv, bt, pos)
     ref = paged_decode_ref(q, ck, cv, bt, pos)

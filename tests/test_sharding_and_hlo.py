@@ -84,7 +84,6 @@ def test_param_specs_resolve_for_all_archs(arch):
 # HLO analyzer
 # ---------------------------------------------------------------------------
 
-_cost_dict = hlo.cost_analysis_dict
 
 
 def test_analyzer_matches_cost_analysis_loop_free():
@@ -96,7 +95,7 @@ def test_analyzer_matches_cost_analysis_loop_free():
     w1 = jnp.ones((256, 512), jnp.float32)
     w2 = jnp.ones((512, 256), jnp.float32)
     comp = jax.jit(f).lower(x, w1, w2).compile()
-    cost = _cost_dict(comp)
+    cost = comp.cost_analysis()
     mine = hlo.analyze(comp.as_text(), 1)
     assert mine.flops == pytest.approx(cost["flops"], rel=0.1)
     assert mine.unknown_trip_loops == 0
@@ -112,7 +111,7 @@ def test_analyzer_folds_scan_trip_counts():
     x = jnp.ones((64, 64), jnp.float32)
     w = jnp.ones((64, 64), jnp.float32)
     comp = jax.jit(g).lower(x, w).compile()
-    cost = _cost_dict(comp)
+    cost = comp.cost_analysis()
     mine = hlo.analyze(comp.as_text(), 1)
     # XLA counts the body once; we fold x5 (plus small outside-loop cost)
     assert 4.0 < mine.flops / cost["flops"] < 5.5

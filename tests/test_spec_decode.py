@@ -87,8 +87,8 @@ VERIFY_CASES = [
 def test_paged_verify_matches_ref(case):
     S, Q, Hk, G, d, N, bs, nb, cursors = case
     q = jnp.asarray(RNG.standard_normal((S, Q, Hk, G, d)), jnp.float32)
-    ck = jnp.asarray(RNG.standard_normal((N, bs, Hk, d)), jnp.float32)
-    cv = jnp.asarray(RNG.standard_normal((N, bs, Hk, d)), jnp.float32)
+    ck = jnp.asarray(RNG.standard_normal((N, Hk, bs, d)), jnp.float32)
+    cv = jnp.asarray(RNG.standard_normal((N, Hk, bs, d)), jnp.float32)
     bt = jnp.asarray(RNG.permutation(N)[:S * nb].reshape(S, nb), jnp.int32)
     pos = jnp.asarray(cursors, jnp.int32)
     out = paged_verify(q, ck, cv, bt, pos)
@@ -102,8 +102,8 @@ def test_paged_verify_q1_is_decode():
     from repro.kernels.paged_attention import paged_decode
     S, Hk, G, d, N, bs, nb = 2, 2, 2, 32, 12, 8, 4
     q = jnp.asarray(RNG.standard_normal((S, Hk, G, d)), jnp.float32)
-    ck = jnp.asarray(RNG.standard_normal((N, bs, Hk, d)), jnp.float32)
-    cv = jnp.asarray(RNG.standard_normal((N, bs, Hk, d)), jnp.float32)
+    ck = jnp.asarray(RNG.standard_normal((N, Hk, bs, d)), jnp.float32)
+    cv = jnp.asarray(RNG.standard_normal((N, Hk, bs, d)), jnp.float32)
     bt = jnp.asarray(RNG.permutation(N)[:S * nb].reshape(S, nb), jnp.int32)
     pos = jnp.asarray((5, 19), jnp.int32)
     one = paged_verify(q[:, None], ck, cv, bt, pos)[:, 0]

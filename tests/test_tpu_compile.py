@@ -1,0 +1,97 @@
+"""Compile-only checks of the engine's Pallas kernels for a TPU v5e.
+
+The TPU compiler compiles for a chip that is described, not attached, so
+these tests catch what interpret mode cannot (block shapes Mosaic
+refuses, VMEM overruns) without a chip.  Shapes are qwen2-7b's published
+widths: 4 KV heads with a query group of 7, head_dim 128, 16-token KV
+blocks, d_model 3584 and d_ff 18944.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.grouped_lora.grouped_lora import grouped_lora_fwd
+from repro.kernels.paged_attention.paged_attention import (
+    paged_decode_fwd, paged_prefill_fwd, paged_verify_fwd)
+
+HK, G, D, BS = 4, 7, 128, 16          # qwen2-7b attention geometry
+N_BLOCKS, MAX_BPS, SLOTS = 512, 64, 8  # a 512-block pool, 1024-token tables
+CHUNK, VERIFY_Q = 256, 5
+D_MODEL, D_FF = 3584, 18944
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """One device of a described v5e host.  Describing the topology loads
+    the TPU compiler library, so it happens here, inside a test, never
+    while a module is imported."""
+    import os
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described chip can write the persistent cache but not read it
+    # back; keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _sds(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _compile(fn, *args):
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+KV_DTYPES = [jnp.bfloat16, jnp.int8]
+
+
+@pytest.mark.parametrize("kv_dtype", KV_DTYPES, ids=["bf16", "int8"])
+def test_paged_decode_compiles(one_chip, kv_dtype):
+    pool = _sds((N_BLOCKS, HK, BS, D), kv_dtype, one_chip)
+    _compile(paged_decode_fwd,
+             _sds((SLOTS, HK, G, D), jnp.bfloat16, one_chip), pool, pool,
+             _sds((SLOTS, MAX_BPS), jnp.int32, one_chip),
+             _sds((SLOTS,), jnp.int32, one_chip))
+
+
+@pytest.mark.parametrize("kv_dtype", KV_DTYPES, ids=["bf16", "int8"])
+def test_paged_prefill_compiles(one_chip, kv_dtype):
+    pool = _sds((N_BLOCKS, HK, BS, D), kv_dtype, one_chip)
+    scalar = _sds((), jnp.int32, one_chip)
+    _compile(paged_prefill_fwd,
+             _sds((CHUNK, HK, G, D), jnp.bfloat16, one_chip), pool, pool,
+             _sds((MAX_BPS,), jnp.int32, one_chip), scalar, scalar)
+
+
+@pytest.mark.parametrize("kv_dtype", KV_DTYPES, ids=["bf16", "int8"])
+def test_paged_verify_compiles(one_chip, kv_dtype):
+    pool = _sds((N_BLOCKS, HK, BS, D), kv_dtype, one_chip)
+    _compile(paged_verify_fwd,
+             _sds((SLOTS, VERIFY_Q, HK, G, D), jnp.bfloat16, one_chip),
+             pool, pool, _sds((SLOTS, MAX_BPS), jnp.int32, one_chip),
+             _sds((SLOTS,), jnp.int32, one_chip))
+
+
+@pytest.mark.parametrize("tokens,n,rank", [(1, D_MODEL, 16),
+                                           (CHUNK, D_FF, 64)],
+                         ids=["q_proj-decode", "mlp-prefill"])
+def test_grouped_lora_compiles(one_chip, tokens, n, rank):
+    pool_slots = 8
+    _compile(grouped_lora_fwd,
+             _sds((SLOTS, tokens, D_MODEL), jnp.bfloat16, one_chip),
+             _sds((pool_slots, D_MODEL, rank), jnp.bfloat16, one_chip),
+             _sds((pool_slots, rank, n), jnp.bfloat16, one_chip),
+             _sds((SLOTS,), jnp.int32, one_chip))
