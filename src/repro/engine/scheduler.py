@@ -20,6 +20,17 @@ Every step appends a :class:`TraceEvent`; the trace is both the measured
 run's structure and the input replayed by the analytical twin
 (``repro.engine.forecast_twin``) to forecast the same serving schedule —
 including how many prompt tokens each admission served from cache.
+
+The engine's host work is marked with profiler spans, all named
+``engine.*``: ``engine.step`` (one :meth:`Engine.step`), ``engine.admit``
+(one admission, or one bucketed group), ``engine.prefill`` /
+``engine.decode`` / ``engine.verify`` (one jitted dispatch, with the
+index ``ev`` of the TraceEvent it produces), ``engine.harvest``,
+``engine.free`` and ``engine.sync`` (each point where the host waits for
+the device, with ``what`` it reads).  They are
+``jax.profiler.TraceAnnotation`` spans: they land on the profiler's host
+plane, on the device trace's clock, and record nothing when no profiler
+runs.  Their arguments are host values only.
 """
 from __future__ import annotations
 
@@ -42,6 +53,17 @@ from .block_pool import BlockPool, RadixIndex
 from .kv_cache import BlockPagedKVCache
 from .decode_loop import (ATTN_IMPLS, make_engine_fns,
                           make_prefill_batch_fn, make_verify_fn, sample)
+
+
+_span = jax.profiler.TraceAnnotation
+
+
+def _sync(what: str, read):
+    """Return ``read()``, which waits for the device, inside an
+    ``engine.sync`` span.  Every point where the scheduler blocks on the
+    device goes through here, so the spans count the syncs."""
+    with _span("engine.sync", what=what):
+        return read()
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -498,9 +520,10 @@ class Engine:
             if valid < ec.chunk_size:
                 piece = np.pad(piece, (0, ec.chunk_size - valid))
             last = off + valid >= n
-            logits, self.state = self.prefill_fn(
-                self.params, self.state, jnp.asarray(piece[None]),
-                jnp.int32(slot), jnp.int32(off), jnp.int32(valid))
+            with _span("engine.prefill", ev=len(self.trace)):
+                logits, self.state = self.prefill_fn(
+                    self.params, self.state, jnp.asarray(piece[None]),
+                    jnp.int32(slot), jnp.int32(off), jnp.int32(valid))
             self.trace.append(TraceEvent(
                 kind="prefill_chunk", rid=req.rid, slot=slot,
                 chunk=valid, past_len=off, cached=cached, last=last,
@@ -514,7 +537,8 @@ class Engine:
                                       self.pool.in_use)
         # the request's first token is sampled from the final prefill logits
         self._rng, sub = jax.random.split(self._rng)
-        first = int(sample(logits[None], ec.temperature, sub)[0])
+        first = _sync("first_token", lambda: int(
+            sample(logits[None], ec.temperature, sub)[0]))
         now = self._now()
         res.first_token = now
         res.tokens.append(first)
@@ -621,10 +645,11 @@ class Engine:
                 ev_members.append((req.rid, slot, len(piece), off, cached,
                                    off + len(piece) >= n))
                 ev_ranks.append(self._slot_rank(slot))
-            logits, self.state = self.prefill_batch_fn(
-                self.params, self.state, jnp.asarray(qtoks),
-                jnp.asarray(slots_arr), jnp.asarray(valids))
-            logits = np.asarray(jax.device_get(logits))
+            with _span("engine.prefill", ev=len(self.trace)):
+                out, self.state = self.prefill_batch_fn(
+                    self.params, self.state, jnp.asarray(qtoks),
+                    jnp.asarray(slots_arr), jnp.asarray(valids))
+            logits = _sync("logits", lambda: np.asarray(jax.device_get(out)))
             for i, (req, slot, prompt, cached, res) in enumerate(members):
                 off = cached + ci * ec.chunk_size
                 if off < len(prompt) and off + valids[i] >= len(prompt):
@@ -641,8 +666,8 @@ class Engine:
                     prompt[:(n // ec.block_size) * ec.block_size],
                     self._slot_blocks[slot][:n // ec.block_size])
             self._rng, sub = jax.random.split(self._rng)
-            first = int(sample(first_logits[i][None], ec.temperature,
-                               sub)[0])
+            first = _sync("first_token", lambda: int(sample(
+                first_logits[i][None], ec.temperature, sub)[0]))
             res.first_token = now
             res.tokens.append(first)
             self.state["tok"] = self.state["tok"].at[slot].set(first)
@@ -656,21 +681,28 @@ class Engine:
                                       self.pool.in_use)
 
     def _free(self, slot: int) -> None:
-        del self.running[slot]
-        for b in self._slot_blocks.pop(slot):
-            self.pool.decref(b)        # index refs keep shared blocks warm
-        aid = self._slot_adapter.pop(slot, None)
-        if aid is not None:
-            # the adapter stays resident (warm for the tenant's next
-            # request) until pool pressure LRU-evicts it
-            self.adapter_pool.release(aid)
-        self.state = self.cache.reset_slot(self.state, slot)
-        self.free_slots.append(slot)
+        with _span("engine.free", slot=slot):
+            del self.running[slot]
+            for b in self._slot_blocks.pop(slot):
+                self.pool.decref(b)    # index refs keep shared blocks warm
+            aid = self._slot_adapter.pop(slot, None)
+            if aid is not None:
+                # the adapter stays resident (warm for the tenant's next
+                # request) until pool pressure LRU-evicts it
+                self.adapter_pool.release(aid)
+            self.state = self.cache.reset_slot(self.state, slot)
+            self.free_slots.append(slot)
 
     # ------------------------------------------------------------------
     # one engine step: admissions, then one fused decode block
     # ------------------------------------------------------------------
     def step(self) -> None:
+        """One engine step: admissions, then one fused decode block (or
+        one speculative verify) over every running slot."""
+        with _span("engine.step", step=self.step_idx, ev=len(self.trace)):
+            self._step()
+
+    def _step(self) -> None:
         ec = self.ec
         if not self.trace:
             # header: the engine knobs the twin's replay/cold_trace need
@@ -699,12 +731,17 @@ class Engine:
                 group = self._take_bucket_group()
                 if not group:
                     break              # pool exhausted: admission backpressure
-                self._admit_batch(group)
+                head, slot, _ = group[0]
+                with _span("engine.admit", rid=head.rid, slot=slot,
+                           n=len(group)):
+                    self._admit_batch(group)
                 continue
             alloc = self._allocate(self.queue[0])
             if alloc is None:
                 break                  # pool exhausted: admission backpressure
-            self._admit(self.queue.popleft(), self.free_slots.pop(0), alloc)
+            req, slot = self.queue.popleft(), self.free_slots.pop(0)
+            with _span("engine.admit", rid=req.rid, slot=slot):
+                self._admit(req, slot, alloc)
         if self.running and ec.spec_k > 0:
             self._spec_step()
         elif self.running:
@@ -713,37 +750,43 @@ class Engine:
             remaining = np.zeros((ec.max_slots,), np.int32)
             for slot, req in sorted(self.running.items()):
                 budget = req.max_new - len(self.results[req.rid].tokens)
-                slots_meta.append((req.rid, int(self.state["pos"][slot]),
-                                   budget))
+                slots_meta.append((req.rid, self._cursor(slot), budget))
                 slot_ranks.append(self._slot_rank(slot))
                 active[slot] = True
                 remaining[slot] = budget
             slots_meta = tuple(slots_meta)
             self._rng, sub = jax.random.split(self._rng)
-            toks, produced, _, self.state = self.decode_fn(
-                self.params, self.state, jnp.asarray(active),
-                jnp.asarray(remaining), sub)
-            jax.block_until_ready(toks)
+            with _span("engine.decode", ev=len(self.trace)):
+                toks, produced, _, self.state = self.decode_fn(
+                    self.params, self.state, jnp.asarray(active),
+                    jnp.asarray(remaining), sub)
+            _sync("ready", lambda: jax.block_until_ready(toks))
             self.trace.append(TraceEvent(
                 kind="decode_block", n_steps=ec.decode_block,
                 slots=slots_meta, adapter_ranks=tuple(slot_ranks)))
-            self._harvest(np.asarray(toks), np.asarray(produced))
+            self._harvest(_sync("tokens", lambda: np.asarray(toks)),
+                          _sync("produced", lambda: np.asarray(produced)))
         self.step_idx += 1
+
+    def _cursor(self, slot: int) -> int:
+        """Slot ``slot``'s KV cursor, read from the device."""
+        return _sync("cursor", lambda: int(self.state["pos"][slot]))
 
     def _harvest(self, toks: np.ndarray, produced: np.ndarray) -> None:
         """Collect the block's sampled tokens; free completed slots."""
-        now = self._now()
-        for slot, req in list(self.running.items()):
-            res = self.results[req.rid]
-            for t in range(toks.shape[0]):
-                if not produced[t, slot]:
-                    break
-                res.tokens.append(int(toks[t, slot]))
-            hit_eos = (self.ec.eos_id is not None and res.tokens
-                       and res.tokens[-1] == self.ec.eos_id)
-            if len(res.tokens) >= req.max_new or hit_eos:
-                res.finished = now
-                self._free(slot)
+        with _span("engine.harvest"):
+            now = self._now()
+            for slot, req in list(self.running.items()):
+                res = self.results[req.rid]
+                for t in range(toks.shape[0]):
+                    if not produced[t, slot]:
+                        break
+                    res.tokens.append(int(toks[t, slot]))
+                hit_eos = (self.ec.eos_id is not None and res.tokens
+                           and res.tokens[-1] == self.ec.eos_id)
+                if len(res.tokens) >= req.max_new or hit_eos:
+                    res.finished = now
+                    self._free(slot)
 
     # ------------------------------------------------------------------
     # speculative decoding: draft k, verify k+1 queries, accept a prefix
@@ -780,17 +823,18 @@ class Engine:
             d = self.drafter.propose(
                 [int(t) for t in req.prompt] + res.tokens, k)
             drafts[slot] = d
-            slots_meta.append((req.rid, int(self.state["pos"][slot]),
-                               budget))
+            slots_meta.append((req.rid, self._cursor(slot), budget))
             active[slot] = True
             valid_q[slot] = 1 + min(k, budget - 1)
             proposed.append(int(valid_q[slot]) - 1)
             qtoks[slot, 0] = res.tokens[-1]
             qtoks[slot, 1:] = d
-        logits, self.state = self.verify_fn(
-            self.params, self.state, jnp.asarray(qtoks),
-            jnp.asarray(active), jnp.asarray(valid_q))
-        logits = np.asarray(jax.device_get(logits))       # (S, k+1, V)
+        with _span("engine.verify", ev=len(self.trace)):
+            out, self.state = self.verify_fn(
+                self.params, self.state, jnp.asarray(qtoks),
+                jnp.asarray(active), jnp.asarray(valid_q))
+        logits = _sync("logits",                          # (S, k+1, V)
+                       lambda: np.asarray(jax.device_get(out)))
         now = self._now()
         accepted = []
         for slot, req in order:

@@ -4,8 +4,13 @@ The TPU compiler compiles for a chip that is described, not attached, so
 these tests catch what interpret mode cannot (block shapes Mosaic
 refuses, VMEM overruns) without a chip.  Shapes are qwen2-7b's published
 widths: 4 KV heads with a query group of 7, head_dim 128, 16-token KV
-blocks, d_model 3584 and d_ff 18944.
+blocks, d_model 3584 and d_ff 18944.  The last test compiles the engine's
+whole prefill and decode steps and checks the names the benchmark's
+trace reduction finds them by.
 """
+import dataclasses
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -95,3 +100,61 @@ def test_grouped_lora_compiles(one_chip, tokens, n, rank):
              _sds((pool_slots, D_MODEL, rank), jnp.bfloat16, one_chip),
              _sds((pool_slots, rank, n), jnp.bfloat16, one_chip),
              _sds((SLOTS,), jnp.int32, one_chip))
+
+
+def test_engine_steps_keep_the_names_the_trace_reduction_reads(
+        one_chip, monkeypatch):
+    """The benchmark finds the engine's programs in a device trace by
+    module name (``jit_prefill``, ``jit_decode``) and the paged kernels by
+    the start of their HLO instruction names
+    (``chipbench/harness/xtrace.py``).  Compile both steps as a cell runs
+    them (paged attention, 128-token blocks, 256-token chunks, 8 fused
+    decode steps; two layers at published widths) and find those names."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec
+    from chipbench.harness import xtrace
+    from repro import configs
+    from repro.engine.decode_loop import make_engine_fns
+    from repro.engine.kv_cache import BlockPagedKVCache
+    from repro.kernels.paged_attention import ops as paged_ops
+    from repro.models import init_params
+    from repro.runtime import ShardingPolicy
+
+    # the kernels choose interpret mode from the backend, which is the CPU
+    # here; compile them as the chip would, and drop traces made for the CPU
+    monkeypatch.setattr(paged_ops, "_on_cpu", lambda: False)
+    jax.clear_caches()
+    cfg = dataclasses.replace(configs.get("qwen2-7b"), n_layers=2)
+    slots, bs, chunk, n_steps = 4, 128, CHUNK, 8
+    mesh = Mesh(np.array(list(one_chip.device_set)).reshape(1, 1),
+                ("data", "model"))
+    cache = BlockPagedKVCache(cfg, slots, n_blocks=16, block_size=bs,
+                              max_blocks_per_seq=4, kv_dtype="bf16")
+    prefill_fn, decode_fn, sh = make_engine_fns(
+        cfg, mesh, ShardingPolicy(), cache, chunk_size=chunk,
+        decode_block=n_steps, attn_impl="paged")
+
+    def shapes(tree, shardings):
+        return jax.tree.map(lambda a, s: _sds(a.shape, a.dtype, s), tree,
+                            shardings)
+
+    params = shapes(jax.eval_shape(
+        lambda: init_params(cfg, jax.random.PRNGKey(0))), sh["params"])
+    state = shapes(jax.eval_shape(cache.init_state), sh["state"])
+    rep = NamedSharding(mesh, PartitionSpec())
+    key = jax.eval_shape(jax.random.PRNGKey, 0)
+    scalar = _sds((), jnp.int32, rep)
+    steps = {
+        "prefill": prefill_fn.lower(
+            params, state, _sds((1, chunk), jnp.int32, rep), scalar, scalar,
+            scalar),
+        "decode": decode_fn.lower(
+            params, state, _sds((slots,), jnp.bool_, rep),
+            _sds((slots,), jnp.int32, rep), _sds(key.shape, key.dtype, rep)),
+    }
+    for step, lowered in steps.items():
+        hlo = lowered.compile().as_text()
+        assert hlo.startswith(f"HloModule jit_{step},"), hlo[:80]
+        kernel = xtrace.KERNEL_PATTERNS[f"paged_{step}"]
+        calls = re.findall(rf"%({kernel}[.\w]*) = \S+ custom-call\(", hlo)
+        assert calls, f"no {kernel} custom call in jit_{step}"
