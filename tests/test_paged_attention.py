@@ -11,7 +11,9 @@ from repro import configs
 from repro.configs.base import Variant
 from repro.core.workload import WorkloadModel
 from repro.engine import Engine, EngineConfig, ForecastTwin, Request
-from repro.kernels.paged_attention import paged_decode, paged_prefill
+from repro.kernels.paged_attention import (paged_attention, paged_decode,
+                                          paged_prefill)
+from repro.kernels.paged_attention.paged_attention import decode_schedule
 from repro.kernels.paged_attention.ref import (paged_decode_ref,
                                                paged_prefill_ref)
 from repro.launch.mesh import make_host_mesh
@@ -56,12 +58,31 @@ def _tol(kv_dtype):
 # kernel vs gather-semantics oracle
 # ---------------------------------------------------------------------------
 
+def _tables(rng, S, N, nb, cursors):
+    """Distinct blocks per slot; a freed slot (cursor 0) keeps a stale row
+    that holds other slots' blocks, as the engine leaves it."""
+    bt = rng.permutation(N)[:S * nb].reshape(S, nb)
+    for s in range(S):
+        if cursors[s] == 0 and S > 1:
+            others = np.delete(bt, s, axis=0).ravel()
+            bt[s] = np.roll(others, 1)[:nb]
+    return bt
+
+
 DECODE_CASES = [
     # (S, Hk, G, d, N, bs, nb, cursors) — cursors exercise block starts,
     # mid-block positions and a fresh slot (pos 0)
     (3, 2, 2, 32, 16, 8, 5, (0, 17, 39)),       # GQA, mid-block cursors
     (2, 4, 1, 64, 12, 16, 3, (16, 31)),         # MHA, block-aligned + last
     (4, 1, 4, 32, 18, 8, 4, (7, 8, 9, 30)),     # MQA around a block seam
+    # 16 pages of 8 keys a compute block (test_decode_schedule_from_shapes),
+    # 20 pages a table: cursors on the last key of the first compute block
+    # and the first key of the second, which is a 4-page tail
+    (2, 2, 2, 32, 48, 8, 20, (127, 128)),
+    # a freed slot beside cursors in the tail block, one on the last key
+    (3, 2, 2, 32, 64, 8, 20, (0, 150, 159)),
+    (3, 4, 7, 32, 24, 8, 6, (5, 0, 47)),        # qwen2-7b's 4 x 7 heads
+    (2, 8, 4, 16, 12, 8, 5, (39, 21)),          # granite-3's 8 x 4 heads
 ]
 
 
@@ -72,9 +93,72 @@ def test_paged_decode_matches_gather_ref(case, kv_dtype):
     rng = _rng(S, Hk, G, d, N, bs, nb, *cursors, KV_CODES[kv_dtype])
     q = jnp.asarray(rng.standard_normal((S, Hk, G, d)), jnp.float32)
     ck, cv = _pool(rng, N, bs, Hk, d, kv_dtype)
-    bt = jnp.asarray(rng.permutation(N)[:S * nb].reshape(S, nb), jnp.int32)
+    bt = jnp.asarray(_tables(rng, S, N, nb, cursors), jnp.int32)
     pos = jnp.asarray(cursors, jnp.int32)
     out = paged_decode(q, ck, cv, bt, pos)
+    ref = paged_decode_ref(q, ck, cv, bt, pos)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=_tol(kv_dtype))
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_decode_reads_no_page_past_a_cursor(kv_dtype):
+    """Every pool page that no slot reaches at or before its cursor is
+    NaN: reserved pages past a cursor, a freed slot's stale row, the pool's
+    unused pages.  A page read and weighted zero would still turn the
+    output NaN, so a finite output equal to the clean pool's proves no
+    such page is fetched or mixed in."""
+    S, Hk, G, d, N, bs, nb = 4, 2, 2, 32, 96, 8, 20
+    cursors = (140, 0, 37, 127)
+    rng = _rng(S, N, bs, nb, *cursors, KV_CODES[kv_dtype])
+    q = jnp.asarray(rng.standard_normal((S, Hk, G, d)), jnp.float32)
+    ck, cv = _pool(rng, N, bs, Hk, d, kv_dtype)
+    bt = _tables(rng, S, N, nb, cursors)
+    reached = {int(bt[s, j]) for s in range(S)
+               for j in range(cursors[s] // bs + 1)}
+    dead = np.setdiff1d(np.arange(N), sorted(reached))
+    assert len(dead) > N // 2
+    bt, pos = jnp.asarray(bt, jnp.int32), jnp.asarray(cursors, jnp.int32)
+    out = paged_decode(q, ck.at[dead].set(jnp.nan), cv.at[dead].set(jnp.nan),
+                       bt, pos)
+    ref = paged_decode_ref(q, ck, cv, bt, pos)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               atol=_tol(kv_dtype))
+
+
+def test_decode_schedule_from_shapes():
+    """Pages and heads per decode step follow from the shapes alone."""
+    # both cells' geometry, 128-token pages: 8 pages with every head
+    assert decode_schedule(8, 128, 64, jnp.bfloat16, 32) == (8, 8)
+    assert decode_schedule(4, 128, 128, jnp.bfloat16, 36) == (8, 4)
+    assert decode_schedule(4, 128, 128, jnp.int8, 36) == (8, 4)
+    # a narrow table caps the pages, and so does the operand count
+    assert decode_schedule(2, 128, 64, jnp.bfloat16, 3) == (3, 2)
+    assert decode_schedule(2, 8, 32, jnp.float32, 20) == (16, 2)
+    # past the VMEM budget: fewer pages first, then a head tile that
+    # divides the heads
+    assert decode_schedule(16, 128, 128, jnp.float32, 32) == (2, 16)
+    assert decode_schedule(24, 128, 256, jnp.float32, 32) == (1, 12)
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.float32, jnp.bfloat16, jnp.int8])
+def test_paged_decode_head_tiles(monkeypatch, kv_dtype):
+    """Where a page of every head would not fit, a step takes a tile of
+    heads; the grid then walks the tiles of each slot in turn."""
+    S, Hk, G, d, N, bs, nb = 3, 4, 2, 32, 16, 8, 5
+    cursors = (30, 0, 13)
+    monkeypatch.setattr(paged_attention, "DECODE_KV_VMEM_BYTES", 40 << 10)
+    assert decode_schedule(Hk, bs, d, jnp.float32, nb) == (1, 2)
+    rng = _rng(S, Hk, N, *cursors, KV_CODES[kv_dtype])
+    q = jnp.asarray(rng.standard_normal((S, Hk, G, d)), jnp.float32)
+    ck, cv = _pool(rng, N, bs, Hk, d, kv_dtype)
+    bt = jnp.asarray(_tables(rng, S, N, nb, cursors), jnp.int32)
+    pos = jnp.asarray(cursors, jnp.int32)
+    out = paged_attention.paged_decode_fwd(q, ck, cv, bt, pos,
+                                           interpret=True)
     ref = paged_decode_ref(q, ck, cv, bt, pos)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),

@@ -72,6 +72,25 @@ def test_paged_decode_compiles(one_chip, kv_dtype):
              _sds((SLOTS,), jnp.int32, one_chip))
 
 
+#: the benchmark cells' decode geometry at 128-token pages:
+#: (slots, KV heads, query group, head_dim, pool blocks, table width)
+CELL_DECODE = {"qwen2-7b-l20": (16, 4, 7, 128, 320, 36),
+               "granite-3-2b": (8, 8, 4, 64, 208, 32)}
+
+
+@pytest.mark.parametrize("kv_dtype", KV_DTYPES, ids=["bf16", "int8"])
+@pytest.mark.parametrize("cell", CELL_DECODE)
+def test_paged_decode_compiles_at_cell_geometry(one_chip, cell, kv_dtype):
+    """Mosaic takes the decode kernel's blocks of several pages of every
+    KV head, and their double buffers fit VMEM, at the cells' sizes."""
+    S, hk, g, d, n, max_bps = CELL_DECODE[cell]
+    pool = _sds((n, hk, 128, d), kv_dtype, one_chip)
+    _compile(paged_decode_fwd,
+             _sds((S, hk, g, d), jnp.bfloat16, one_chip), pool, pool,
+             _sds((S, max_bps), jnp.int32, one_chip),
+             _sds((S,), jnp.int32, one_chip))
+
+
 @pytest.mark.parametrize("kv_dtype", KV_DTYPES, ids=["bf16", "int8"])
 def test_paged_prefill_compiles(one_chip, kv_dtype):
     pool = _sds((N_BLOCKS, HK, BS, D), kv_dtype, one_chip)
