@@ -6,11 +6,11 @@ its host work: ``engine.step`` (one ``Engine.step()``), ``engine.admit``,
 dispatch each, with its trace event's index ``ev``), ``engine.harvest``,
 ``engine.free``, and ``engine.sync`` (each point where the host waits for
 the device, with ``what`` it reads).  They lie on the profiler's host
-plane, on the device trace's clock.  ``xtrace.reduce`` keeps only the
-harness's own spans (``cb.*``), so this module reads the engine's from the
-same profile, within the window ``xtrace.reduce`` found.
+plane, on the device trace's clock; ``xtrace.reduce`` keeps those that
+start in the window, by start, in ``Reduced.engine_spans``.
 
-From them:
+From them (the readers ``chipbench/metrics/host_syncs_per_step.py`` and
+``sched_idle_ms_per_step.py``, and ``run.py``'s ``[engine_idle]`` line):
 
 - ``host_syncs_per_step``: ``engine.sync`` spans per ``engine.step`` that
   dispatched a decode block (held an ``engine.decode``);
@@ -42,7 +42,6 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from . import xtrace as X
 
-PREFIX = "engine."
 #: the engine's jitted steps; any other program is one the host issued
 #: eagerly (a slot-state write, a cursor read, a first-token sample)
 ENGINE_PROGRAMS = frozenset({"jit_prefill", "jit_decode",
@@ -50,16 +49,6 @@ ENGINE_PROGRAMS = frozenset({"jit_prefill", "jit_decode",
 OUTSIDE = "outside engine spans"
 #: the harness's gap labels that fall inside its ``cb.step`` span
 STEP_GAPS = ("admit", "admit.prefill", "decode", "harvest")
-
-
-def engine_spans(pd, red: X.Reduced) -> List[X.Event]:
-    """The ``engine.*`` host spans of profile ``pd`` that start in
-    ``red``'s window, by start."""
-    spans = [X._event(ev) for plane in pd.planes
-             if plane.name.startswith("/host:")
-             for line in plane.lines for ev in line.events
-             if ev.name.startswith(PREFIX)]
-    return sorted((s for s in spans if red.within(s)), key=lambda s: s.start)
 
 
 class Cover:
@@ -225,9 +214,8 @@ def main(argv=None) -> int:
     if len(args) != 1:
         print(__doc__, file=sys.stderr)
         return 2
-    pd = X.load(_trace_file(Path(args[0])))
-    red = X.reduce(pd)
-    spans = engine_spans(pd, red)
+    red = X.reduce(X.load(_trace_file(Path(args[0]))))
+    spans = red.engine_spans
     for tag, facts in (("engine_idle", engine_idle(red, spans)),
                        ("engine_spans", summary(red, spans))):
         print(f"[{tag}] {json.dumps(facts, sort_keys=True)}", flush=True)

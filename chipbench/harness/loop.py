@@ -14,9 +14,10 @@ The load starts before the window opens: an open loop's arrivals run
 ``warm_s`` first, a closed loop waits until every caller has its first
 token and then ``warm_s``.  So the window opens on an engine already in
 its steady state.  After the window closes the load goes on until every
-request of the window has its first token and at least one more (or
-``drain_cap_s`` passes; a request still without a first token then
-counts as failed).
+request of the window has served all its tokens (or ``drain_cap_s``
+passes; a request still without a first token then counts as failed), so
+that each request's time per output token is taken over its whole output
+and not over the part served before the close.
 
 Times are ``time.perf_counter()`` seconds.  The engine stamps
 ``admitted`` and ``first_token`` relative to its own ``_t0``; they are
@@ -214,8 +215,7 @@ def serve(engine, traffic: Traffic, seconds: float, drain_cap_s: float,
         steps.append(StepRec(t0, t1, lo, len(engine.trace)))
 
     def window_done():
-        return all(r.n_tokens >= 2 or r.finished
-                   for r in recs.values() if r.in_window)
+        return all(r.finished for r in recs.values() if r.in_window)
 
     t_load = clock()
     if closed:

@@ -2,6 +2,7 @@
 from __future__ import annotations
 
 import dataclasses
+from types import ModuleType
 from typing import Dict, List, Optional
 
 from .loop import Served
@@ -11,6 +12,7 @@ from .xtrace import Reduced
 @dataclasses.dataclass
 class Run:
     arch: Dict                  # the configuration's model sizes
+    architecture: ModuleType    # its module (chipbench/arch): the counts
     engine: Dict                # the configuration's engine settings
     peaks: Dict                 # the chip's published peaks
     served: Served              # the harness's records of the window

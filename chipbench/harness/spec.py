@@ -3,8 +3,17 @@
 A cell names a configuration and a traffic mix; each is a file of its own
 (``chipbench/configs/<config>.json``, ``chipbench/traffic/<mix>.json``),
 and each per-layer metric is a reader ``chipbench/metrics/<metric>.py``.
-Adding a cell, a configuration, a mix or a metric adds files and entries
-and edits none.
+A configuration's ``reference`` key names its architecture: the module
+``chipbench/arch/<reference>.py`` (the program's config, the weights'
+layout, the counts of useful work; see ``chipbench/arch/__init__.py``)
+and the plain reference ``chipbench/reference/<reference>.py``.
+
+Adding a cell, a configuration, a mix, a metric or an architecture adds
+files and entries and edits none.  A configuration of a new kind of model
+brings its architecture as those two new modules, and names them in its
+``reference`` key; a reader of a new kernel asks the architecture's
+``kernel_calls`` for the kernel by name and finds its events in the trace
+by that name (``xtrace.kernel_ops``).
 """
 from __future__ import annotations
 
@@ -30,6 +39,10 @@ class Cell:
     @property
     def arch(self) -> Dict:
         return self.config["arch"]
+
+    @property
+    def architecture(self):
+        return architecture(self.config)
 
 
 def _load_json(path: Path) -> Dict:
@@ -59,6 +72,11 @@ def reader(metric: str):
     """The per-layer metric's reader: ``read(run) -> float | None``."""
     mod = importlib.import_module(f"chipbench.metrics.{metric}")
     return mod.read
+
+
+def architecture(config: Dict):
+    """The configuration's architecture module."""
+    return importlib.import_module(f"chipbench.arch.{config['reference']}")
 
 
 def reference(config: Dict):

@@ -9,9 +9,15 @@ per operation run and its ``XLA Modules`` line one per program
 execution (``jit_prefill``, ``jit_decode``, ...).  The host plane holds
 the harness's own spans (``cb.step``, ``cb.prefill``, ``cb.decode``,
 ``cb.submit``, ``cb.wait``; see ``harness.loop``), on the same clock.
-The profiler runs from the window's opening until after the drain; the
-span ``cb.window`` marks the measured window, and every number read here
-is of that window.
+The program's own host spans (``engine.*``, opened by the serving
+engine's scheduler; ``harness.engine_spans`` reads them) are kept apart,
+in ``Reduced.engine_spans``.  The profiler runs from the window's opening
+until after the drain; the span ``cb.window`` marks the measured window,
+and every number read here is of that window.
+
+A kernel is found by the start of its HLO instruction's name, which is
+the jitted wrapper's (``%paged_decode.12 = bf16[...] custom-call(...)``),
+so ``kernel_ops`` takes any kernel's name.
 """
 from __future__ import annotations
 
@@ -25,11 +31,12 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:\d+$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
 SPAN_PREFIX = "cb."
+ENGINE_PREFIX = "engine."
 WINDOW_SPAN = "cb.window"
 
-#: how a Pallas kernel shows in the ``XLA Ops`` line: the start of its
-#: HLO instruction's name, which is the jitted wrapper's (``%paged_decode.12
-#: = bf16[...] custom-call(...)``)
+#: the paged kernels' instruction names, which ``tests/test_tpu_compile.py``
+#: looks for in the engine's compiled steps; ``kernel_ops`` does not need
+#: them
 KERNEL_PATTERNS = {
     "paged_decode": "paged_decode",
     "paged_prefill": "paged_prefill",
@@ -76,6 +83,8 @@ class Reduced:
     t1: float
     devices: List[Device]
     spans: List[Event]          # harness host spans, by start
+    engine_spans: List[Event] = dataclasses.field(default_factory=list)
+                                # the program's, that start in the window
 
     def within(self, e: Event) -> bool:
         """Whether ``e`` started inside the window."""
@@ -137,7 +146,7 @@ def load(path_or_bytes):
 
 
 def reduce(pd) -> Reduced:
-    devices, spans, window = [], [], None
+    devices, spans, engine, window = [], [], [], None
     lo, hi = float("inf"), float("-inf")
     for plane in pd.planes:
         stats = dict(plane.stats)
@@ -164,6 +173,8 @@ def reduce(pd) -> Reduced:
                         e = _event(ev)
                         spans.append(e)
                         lo, hi = min(lo, e.start), max(hi, e.end)
+                    elif ev.name.startswith(ENGINE_PREFIX):
+                        engine.append(_event(ev))
     if not devices:
         raise ValueError("trace has no TPU device plane")
     spans.sort(key=lambda e: e.start)
@@ -173,7 +184,9 @@ def reduce(pd) -> Reduced:
     else:
         t0 = 0.0 if window is not None else lo
         t1 = window if window is not None else hi
-    return Reduced(t0, t1, devices, spans)
+    engine = sorted((e for e in engine if t0 <= e.start < t1),
+                    key=lambda e: e.start)
+    return Reduced(t0, t1, devices, spans, engine)
 
 
 def base_name(name: str) -> str:
@@ -220,14 +233,14 @@ def match_modules(red: Reduced, module: str, span: str
 
 def kernel_ops(red: Reduced, kernel: str, lo: float, hi: float
                ) -> List[Event]:
-    """Device-0 events of ``kernel`` that start within ``[lo, hi)``."""
-    pat = KERNEL_PATTERNS[kernel]
+    """Device-0 events of ``kernel`` (those whose HLO instruction's name
+    starts with it) that start within ``[lo, hi)``."""
     dev = red.devices[0]
     ops = dev.ops
     i = bisect.bisect_left(dev.starts, lo)
     out = []
     while i < len(ops) and ops[i].start < hi:
-        if ops[i].op.startswith(pat):
+        if ops[i].op.startswith(kernel):
             out.append(ops[i])
         i += 1
     return out

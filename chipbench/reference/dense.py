@@ -16,7 +16,7 @@ Granite 3.0, with the departures that the configuration file lists:
 
 It imports nothing of the serving program.  It runs after the program's
 state is freed: weights are made again from the seed one layer at a
-time (``harness.weights``), and the whole sequence goes through one
+time (``chipbench/arch/dense.py``), and the whole sequence goes through one
 layer per call, so only one layer's weights and one sequence's
 activations exist at a time.  Matrix products run at ``highest``
 precision, every activation is float32.
@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from chipbench.arch import dense as dense_arch
 from chipbench.harness import weights as W
 
 F8 = jnp.float8_e4m3fn
@@ -113,12 +114,12 @@ def _layer(x, p, a, mode):
 
 @functools.partial(jax.jit, static_argnames=("a",))
 def _make_layer(key, i, a):
-    return W.layer_weights(key, dict(a), i)
+    return dense_arch.layer_weights(key, dict(a), dense_arch.STACK, i)
 
 
 @functools.partial(jax.jit, static_argnames=("a",))
 def _make_outer(key, a):
-    return W.outer_weights(key, dict(a))
+    return dense_arch.outer_weights(key, dict(a))
 
 
 @functools.partial(jax.jit, static_argnames=("a", "mode"))

@@ -16,8 +16,14 @@ line of standard output, one JSON object: ``correct``, ``attempted``,
 the per-layer ones), ``device``, with ``--trace 1`` ``breakdown``, and
 last ``check``: each number compared beside its limit.  Earlier lines
 (``[tag] {json}``) give the set-up, programs compiled inside the window
-(there should be none), how late the traffic generator ran, and the
-analytical twin's forecast of the run's own trace.
+(there should be none), how late the traffic generator ran, the
+analytical twin's forecast of the run's own trace, and with ``--trace 1``
+the device's idle between programs by the engine's own innermost span
+(``[engine_idle]``, ``harness.engine_spans``).
+
+The configuration's ``reference`` key names its architecture
+(``chipbench/arch``), which gives the program's config, the weights'
+layout and the counts the readers price.
 
 Without a TPU, with fewer chips than the cell asks for, or on a chip
 missing from ``harness/peaks.py``, it exits non-zero and prints no
@@ -61,14 +67,6 @@ def enable_cache() -> None:
     jax.config.update("jax_compilation_cache_dir", str(CACHE_DIR))
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
-
-
-def arch_config(arch: dict):
-    """The program's ``ArchConfig`` for a configuration's sizes."""
-    import dataclasses
-    from repro.configs.base import ArchConfig
-    fields = {f.name for f in dataclasses.fields(ArchConfig)}
-    return ArchConfig(**{k: v for k, v in arch.items() if k in fields})
 
 
 def warm_slots(engine, ec: dict) -> None:
@@ -116,7 +114,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     (tests); ``control`` also scores the fp8 control on the sample."""
     import jax
     import numpy as np
-    from chipbench.harness import check, loop, traffic, weights, xtrace
+    from chipbench.harness import (check, engine_spans, loop, traffic,
+                                   weights, xtrace)
     from chipbench.harness.compile_log import CompileLog
     from chipbench.harness.record import Run
     from repro.engine import Engine, EngineConfig
@@ -125,10 +124,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
 
     enable_cache()
     log = CompileLog()
-    a, ec = cell.arch, cell.config["engine"]
-    cfg = arch_config(a)
+    a, ec, architecture = cell.arch, cell.config["engine"], cell.architecture
+    cfg = architecture.arch_config(a)
     dev = jax.devices()[0]
-    params = weights.make_params(a, seed)
+    params = weights.make_params(architecture, a, seed)
     t_weights = time.perf_counter() - T_START
     engine = Engine(cfg, params, make_host_mesh(), ShardingPolicy(),
                     EngineConfig(**ec))
@@ -138,7 +137,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     stats = dev.memory_stats() or {}
     _emit("setup", {"weights_ready_s": t_weights,
                     "warm_s": time.perf_counter() - T_START,
-                    "weight_bytes": weights.param_bytes(a),
+                    "weight_bytes": weights.param_bytes(architecture, a),
                     "bytes_in_use": stats.get("bytes_in_use"),
                     "bytes_limit": stats.get("bytes_limit"),
                     **log.snapshot()})
@@ -183,6 +182,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         / (served.t_close - served.t_open),
         "ttft_p50_ms": 1e3 * traffic.percentile(ttft, 50),
         "ttft_p95_ms": 1e3 * traffic.percentile(ttft, 95),
+        "tpot_p50_ms": 1e3 * traffic.percentile([r.tpot for r in done], 50),
         "tpot_p95_ms": 1e3 * traffic.percentile([r.tpot for r in done], 95),
         "setup_s": served.t_open - T_START,
     }
@@ -207,8 +207,8 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
     except Exception as e:       # the forecast is a record, not a result
         _emit("forecast", {"error": repr(e)})
 
-    run = Run(arch=a, engine=ec, peaks=peaks, served=served, events=events,
-              trace=None)
+    run = Run(arch=a, architecture=architecture, engine=ec, peaks=peaks,
+              served=served, events=events, trace=None)
     if trace:
         t0 = time.perf_counter()
         files = sorted(tdir.rglob("*.xplane.pb"))
@@ -217,7 +217,10 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, trace: bool, *,
         if trace_dir is None:
             shutil.rmtree(tdir, ignore_errors=True)
         _emit("trace", {"bytes": size, "reduce_s": time.perf_counter() - t0,
-                        "ops": len(run.trace.devices[0].ops)})
+                        "ops": len(run.trace.devices[0].ops),
+                        "engine_spans": len(run.trace.engine_spans)})
+        _emit("engine_idle", engine_spans.engine_idle(
+            run.trace, run.trace.engine_spans))
     metrics = {}
     if trace:
         for m in cell.per_layer:

@@ -74,8 +74,9 @@ def main(argv=None) -> int:
     from repro.launch.mesh import make_host_mesh
     from repro.runtime import ShardingPolicy
     R.enable_cache()
-    a, ec = cell.arch, cell.config["engine"]
-    engine = Engine(R.arch_config(a), weights.make_params(a, args.seed),
+    a, ec, architecture = cell.arch, cell.config["engine"], cell.architecture
+    engine = Engine(architecture.arch_config(a),
+                    weights.make_params(architecture, a, args.seed),
                     make_host_mesh(), ShardingPolicy(), EngineConfig(**ec))
     engine.warmup()
     R.warm_slots(engine, ec)

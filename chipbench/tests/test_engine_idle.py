@@ -17,8 +17,9 @@ from chipbench.harness import engine_spans as E
 from chipbench.harness import xtrace as X
 from chipbench.tests import test_xtrace as T
 from chipbench.metrics import (device_idle_share, decode_step_ms,
-                               paged_decode_roofline, paged_prefill_roofline,
-                               prefill_chunk_ms)
+                               host_syncs_per_step, paged_decode_roofline,
+                               paged_prefill_roofline, prefill_chunk_ms,
+                               sched_idle_ms_per_step)
 
 # device 0: (line, name, start, end, long_name)
 DEVICE = [
@@ -91,7 +92,7 @@ def _profile(device, host):
 def read():
     pd = _profile(DEVICE, HARNESS + ENGINE)
     red = X.reduce(pd)
-    return red, E.engine_spans(pd, red)
+    return red, red.engine_spans
 
 
 def test_spans_read_in_the_window(read):
@@ -118,6 +119,20 @@ def test_sched_idle_counts_gaps_between_programs_only(read):
     red, spans = read
     assert E.sched_idle_ms_per_step(red, spans) == pytest.approx(
         (21_000 + 4_000) / 2 / 1e6)
+
+
+def test_readers_take_the_engine_spans_from_the_reduced_trace(read):
+    red, _ = read
+    run = T._run(red)
+    assert host_syncs_per_step.read(run) == 4.0
+    assert sched_idle_ms_per_step.read(run) == pytest.approx(
+        (21_000 + 4_000) / 2 / 1e6)
+    for reader in (host_syncs_per_step, sched_idle_ms_per_step):
+        assert reader.read(T._run(None)) is None
+    # a trace without the engine's spans has nothing for them to read
+    bare = X.reduce(_profile(DEVICE, HARNESS))
+    assert host_syncs_per_step.read(T._run(bare)) is None
+    assert sched_idle_ms_per_step.read(T._run(bare)) is None
 
 
 def test_engine_idle_by_innermost_span(read):
@@ -167,7 +182,7 @@ def test_cover_by_bisection_agrees_with_xtrace():
 
 def test_innermost_pieces_tile_the_spans():
     red = X.reduce(_profile(DEVICE, ENGINE))
-    spans = E.engine_spans(_profile(DEVICE, ENGINE), red)
+    spans = red.engine_spans
     pieces = E.innermost(spans)
     assert all(a < b for a, b, _ in pieces)
     assert all(p[1] <= q[0] for p, q in zip(pieces, pieces[1:]))

@@ -92,7 +92,8 @@ def test_profiler_brackets_the_window_only():
 
     c = tiny.cell("tiny-open")
     a, ec = c.arch, c.config["engine"]
-    engine = Engine(run.arch_config(a), weights.make_params(a, SEED),
+    engine = Engine(c.architecture.arch_config(a),
+                    weights.make_params(c.architecture, a, SEED),
                     make_host_mesh(), ShardingPolicy(), EngineConfig(**ec))
     calls = []
     tr = {"start": lambda: calls.append(("start", time.perf_counter())),

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from chipbench.arch import dense as dense_arch
 from chipbench.harness import weights as W
 from chipbench.reference import dense
 from chipbench.tests import tiny
@@ -16,15 +17,14 @@ from chipbench.tests import tiny
 @pytest.mark.parametrize("tied", [False, True])
 def test_reference_matches_program_forward(tied):
     from repro.models import forward
-    from chipbench.run import arch_config
     a = dict(tiny.cell().arch, tie_embeddings=tied, qkv_bias=not tied)
     seed = 77
     T = 40
     toks = np.random.default_rng(0).integers(0, a["vocab_size"], T)
     p32 = jax.tree_util.tree_map(lambda x: x.astype(jnp.float32),
-                                 W.make_params(a, seed))
+                                 W.make_params(dense_arch, a, seed))
     with jax.default_matmul_precision("highest"):
-        want = np.asarray(forward(arch_config(a), p32,
+        want = np.asarray(forward(dense_arch.arch_config(a), p32,
                                   jnp.asarray(toks[None], jnp.int32))[0][0])
     targets = want.argmax(-1).astype(np.int32)
     sc = dense.score(a, seed, toks.astype(np.int32), targets)

@@ -4,6 +4,7 @@ from __future__ import annotations
 
 import pytest
 
+from chipbench.arch import dense
 from chipbench.harness import xtrace as X
 from chipbench.harness.loop import Served, StepRec
 from chipbench.harness.record import Run
@@ -134,7 +135,8 @@ def _run(red):
               _Ev("decode_block", slots=((1, 99, 8), (2, 9, 3)))]
     served = Served(recs=[], steps=[StepRec(0, 1, 0, 0)], t_open=0,
                     t_close=1, tokens_in_window=0, lateness=[])
-    return Run(arch=ARCH, engine={"decode_block": 8, "kv_dtype": "bf16"},
+    return Run(arch=ARCH, architecture=dense,
+               engine={"decode_block": 8, "kv_dtype": "bf16"},
                peaks=PEAKS, served=served, events=events, trace=red)
 
 
@@ -213,3 +215,47 @@ def test_every_metric_has_a_reader_and_moves_what_its_cells_report():
         for m in cell.per_layer:
             assert callable(spec.reader(m["name"]))
             assert m["moves"] in reported, (w["name"], m["name"])
+
+
+def test_kernel_ops_takes_a_kernel_in_no_table():
+    from jax.profiler import ProfileData
+    ops = {"XLA Ops": [
+        ("XLA Ops", "%mla_decode.3 = bf16[4] custom-call()", 0, 5_000, ""),
+        ("XLA Ops", "%fusion.1 = bf16[4] fusion(%mla_decode.3)", 5_000,
+         6_000, ""),
+        ("XLA Ops", "%mla_decode.4 = bf16[4] custom-call()", 7_000, 9_000,
+         "")]}
+    env = trace_text().split("\n")[-1]
+    red = X.reduce(ProfileData.from_text_proto(
+        "\n".join([_plane(1, "/device:TPU:0", ops), env])))
+    assert "mla_decode" not in X.KERNEL_PATTERNS
+    assert [o.op for o in X.kernel_ops(red, "mla_decode", 0, 10_000)] == [
+        "mla_decode.3", "mla_decode.4"]
+    assert [o.op for o in X.kernel_ops(red, "mla_decode", 6_000, 10_000)] \
+        == ["mla_decode.4"]
+
+
+def test_reduce_keeps_the_programs_spans_apart():
+    """``engine.*`` spans that start in the window are kept, by start and
+    with their arguments, in ``engine_spans``; the harness's spans and
+    every reading taken from them are what they were without them."""
+    from jax.profiler import ProfileData
+    from chipbench.metrics import step_mfu
+    engine = [("engine.decode", 1_000, 2_000, 4),
+              ("engine.step", 0, 49_000, None),
+              ("engine.sync", 99_000, 101_000, None),
+              ("engine.sync", 100_000, 101_000, None)]      # after the window
+    without, with_ = [X.reduce(ProfileData.from_text_proto(trace_text(s)))
+                      for s in (HOST, HOST + engine)]
+    assert without.engine_spans == []
+    assert [(s.name, s.start, s.end) for s in with_.engine_spans] == [
+        ("engine.step", 0, 49_000), ("engine.decode", 1_000, 2_000),
+        ("engine.sync", 99_000, 101_000)]
+    assert with_.engine_spans[1].stats["ev"] == 4
+    assert [(s.name, s.start, s.end, s.stats) for s in with_.spans] == [
+        (s.name, s.start, s.end, s.stats) for s in without.spans]
+    assert (with_.t0, with_.t1, with_.busy_s) == (
+        without.t0, without.t1, without.busy_s)
+    for mod in (device_idle_share, decode_step_ms, paged_decode_roofline,
+                paged_prefill_roofline, prefill_chunk_ms, step_mfu):
+        assert mod.read(_run(with_)) == mod.read(_run(without))
